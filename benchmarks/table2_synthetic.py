@@ -57,6 +57,9 @@ def main(full: bool = False, out=print):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
 
     main(full="--full" in sys.argv)

@@ -58,6 +58,9 @@ def main(full: bool = False, data: str | None = None, out=print):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
 
     data = None

@@ -54,7 +54,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size as _compat_axis_size, shard_map as _shard_map
 from repro.config import GossipMCConfig
 from repro.core import objective as obj
 from repro.core.state import Problem, State
@@ -122,10 +121,6 @@ def _shift(x, axis_name, mesh_size, direction: int):
     return jax.lax.ppermute(x, axis_name, perm)
 
 
-def _axis_size(axis_name) -> int:
-    return _compat_axis_size(axis_name)
-
-
 def exchange_halos(U, W, row_axes, col_axes, compression="none",
                    ef=None, topk_fraction=0.25, age=None):
     """One gossip exchange; returns HaloState + updated error feedback.
@@ -136,8 +131,8 @@ def exchange_halos(U, W, row_axes, col_axes, compression="none",
     ``make_gossip_step``); when omitted, a fresh all-received age of 0 is
     used — every message of this exchange did arrive."""
 
-    dc = _axis_size(col_axes)
-    dr = _axis_size(row_axes)
+    dc = jax.lax.axis_size(col_axes)
+    dr = jax.lax.axis_size(row_axes)
     msgs = {
         "u_last": U[:, -1],   # -> right neighbour's left_u
         "u_first": U[:, 0],   # -> left neighbour's right_u
@@ -198,8 +193,8 @@ def _local_gradients(problem: Problem, U, W, halos: HaloState,
 
     c = jax.lax.axis_index(col_axes)
     r_ = jax.lax.axis_index(row_axes)
-    dc = _axis_size(col_axes)
-    dr = _axis_size(row_axes)
+    dc = jax.lax.axis_size(col_axes)
+    dr = jax.lax.axis_size(row_axes)
 
     if gates is None:
         left_h, right_h = halos.left_u, halos.right_u
@@ -371,8 +366,8 @@ def make_gossip_step(
         if faults is not None or async_rounds:
             c = jax.lax.axis_index(col_axes)
             r_ = jax.lax.axis_index(row_axes)
-            dc = _axis_size(col_axes)
-            dr = _axis_size(row_axes)
+            dc = jax.lax.axis_size(col_axes)
+            dr = jax.lax.axis_size(row_axes)
             # which of my 4 halo directions have a real neighbour
             exists = jnp.stack([c > 0, c < dc - 1, r_ > 0, r_ < dr - 1])
             if faults is not None:
@@ -485,7 +480,7 @@ def make_gossip_step(
         in_specs = (problem_spec, carry_spec)
         body_fn = shard_body
     step = jax.jit(
-        _shard_map(
+        jax.shard_map(
             body_fn,
             mesh=mesh,
             in_specs=in_specs,
@@ -602,7 +597,7 @@ def _distributed_cost_fn(plan: MeshPlan, lam: float, sparse: bool):
         return jax.lax.psum(c, axes)
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             local_cost, mesh=plan.mesh,
             in_specs=(problem_spec, pspec2, pspec2),
             out_specs=P(),

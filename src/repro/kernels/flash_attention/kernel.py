@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
 _LANES = 128
 _NEG_INF = -1e30
 
@@ -132,7 +130,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, _LANES), jnp.float32),   # running denom
             pltpu.VMEM((bq, D), jnp.float32),        # output accumulator
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

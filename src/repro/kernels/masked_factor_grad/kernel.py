@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
 
 def _kernel(x_ref, m_ref, u_ref, w_ref, loss_ref, gu_ref, gw_ref, *, bn: int):
     i = pl.program_id(0)
@@ -100,7 +98,7 @@ def masked_factor_grad_pallas(x, mask, u, w, *, bm: int, bn: int, interpret: boo
             jax.ShapeDtypeStruct((M, r), jnp.float32),
             jax.ShapeDtypeStruct((N, r), jnp.float32),
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.masked_factor_grad.kernel import masked_factor_grad_pallas
 from repro.kernels.masked_factor_grad.ref import masked_factor_grad_ref
 
@@ -68,6 +69,8 @@ def masked_factor_grad(
     if resident > _MAX_RESIDENT_BYTES and not force_kernel:
         # gW accumulator would not fit VMEM — the factor rank is too large
         # for the fused layout; use the reference (XLA fuses adequately).
+        obs.counter("kernel_fallbacks_total", kernel="masked_factor_grad",
+                    reason="vmem").inc()
         return masked_factor_grad_ref(x, mask, u, w)
 
     xp = _pad2(x, Mp, Np)

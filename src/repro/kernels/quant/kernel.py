@@ -32,8 +32,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.compat import pallas_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(uq_ref, us_ref, wq_ref, ws_ref, out_ref):
@@ -69,7 +68,7 @@ def dequant_score_pallas(u_q, u_scale, w_q, w_scale, *,
         ],
         out_specs=pl.BlockSpec((b, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -18,6 +18,11 @@ route through it when handed a quantized index).  It follows the
   grid step) and backs off to the XLA emulation when the resident batch
   tile would not fit.
 
+Both fused-method fallbacks (``reason="interpret"`` off-TPU,
+``reason="vmem"`` over budget) increment
+``kernel_fallbacks_total{kernel="quant_fused", reason=...}`` while the
+caller's program is traced, once per compiled program.
+
 Padding contract: rank pads to the 128-lane boundary, the user batch to
 int8 sublane multiples, the catalog to ``bn`` multiples — padded rows
 carry ``q = 0, scale = 0`` (score exactly 0) and are sliced away before
@@ -31,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.quant.autotune import resolve_method
 from repro.kernels.quant.kernel import dequant_score_pallas
 from repro.kernels.quant.ref import dequant_score_ref, fused_score_xla
@@ -77,6 +83,8 @@ def dequant_score(
     if interpret and not force_kernel:
         # fused arithmetic without Mosaic: the XLA emulation is the same
         # int32-accumulate + epilogue, bit-identical to the kernel.
+        obs.counter("kernel_fallbacks_total", kernel="quant_fused",
+                    reason="interpret").inc()
         return fused_score_xla(u_q, u_scale, w_q, w_scale)
 
     r_pad = _round_up(max(r, _LANE), _LANE)
@@ -90,6 +98,8 @@ def dequant_score(
         + b_pad * bn_eff * 4                      # f32 output tile
     )
     if vmem > _MAX_VMEM_BYTES and not force_kernel:
+        obs.counter("kernel_fallbacks_total", kernel="quant_fused",
+                    reason="vmem").inc()
         return fused_score_xla(u_q, u_scale, w_q, w_scale)
 
     uq = jnp.pad(u_q, ((0, b_pad - B), (0, r_pad - r)))

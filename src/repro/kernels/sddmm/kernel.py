@@ -32,7 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
+# the one-hot matmuls gather and scatter f32 values: at full f32 precision
+# to f32 accuracy, where one bf16 pass (the TPU's default) keeps 8 bits
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _kernel(rows_ref, cols_ref, vals_ref, valid_ref, u_ref, w_ref,
@@ -60,10 +62,12 @@ def _kernel(rows_ref, cols_ref, vals_ref, valid_ref, u_ref, w_ref,
             ).astype(jnp.float32)               # (be, N)
 
     ue = jax.lax.dot_general(                   # gather U[rows]: (be, r)
-        oh_r, u, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        oh_r, u, (((1,), (0,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32,
     )
     we = jax.lax.dot_general(                   # gather W[cols]: (be, r)
-        oh_c, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        oh_c, w, (((1,), (0,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32,
     )
     e = valid * (vals - jnp.sum(ue * we, axis=1))       # (be,)
     loss_ref[0, 0] += jnp.sum(e * e)
@@ -71,10 +75,12 @@ def _kernel(rows_ref, cols_ref, vals_ref, valid_ref, u_ref, w_ref,
     d = -2.0 * e[:, None]                       # (be, 1)
     # scatter-add into the resident accumulators: contract the entry axis.
     gu_ref[...] += jax.lax.dot_general(
-        oh_r, d * we, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        oh_r, d * we, (((0,), (0,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32,
     )
     gw_ref[...] += jax.lax.dot_general(
-        oh_c, d * ue, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        oh_c, d * ue, (((0,), (0,)), ((), ())), precision=_EXACT,
+        preferred_element_type=jnp.float32,
     )
 
 
@@ -110,7 +116,7 @@ def sddmm_factor_grad_pallas(rows, cols, vals, valid, u, w, *,
             jax.ShapeDtypeStruct((m, r), jnp.float32),
             jax.ShapeDtypeStruct((n, r), jnp.float32),
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

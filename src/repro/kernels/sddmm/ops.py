@@ -8,7 +8,10 @@ so they contribute nothing), pad r to the 128-lane boundary and M/N to
 sublane multiples (zero factor rows whose gradients are exactly zero and
 are sliced away), pick interpret mode automatically off-TPU, and fall back
 to the XLA path whenever the resident working set would blow the VMEM
-budget — there the O(nnz·r) XLA paths win anyway.  The raw
+budget.  Each such fallback increments
+``kernel_fallbacks_total{kernel=..., reason="vmem"}`` while the caller's
+program is traced (once per compiled program, not per call), so a run
+that asked for the kernel can check that it got it.  The raw
 ``*_pallas`` functions keep exploded padded-array signatures: that is the
 kernel ABI (tile-aligned device buffers), not the sparse API surface.
 
@@ -25,6 +28,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.sddmm.kernel import sddmm_factor_grad_pallas
 from repro.kernels.sddmm.ref import sddmm_factor_grad_ref
 from repro.kernels.sddmm.segment import sddmm_segment_grad_ref
@@ -38,6 +42,22 @@ _MAX_VMEM_BYTES = 10 * 1024 * 1024
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def segment_vmem_bytes(M: int, N: int, r: int, E: int, be: int = 512) -> int:
+    """VMEM the segment kernel keeps resident for one (M, N, rank r)
+    block of capacity E: U/W and a gradient accumulator, the one-hot and
+    scan-triangle tiles, and the boundary-difference matrix."""
+
+    r_pad = _round_up(max(r, _LANE), _LANE)
+    m_pad = _round_up(M, _SUBLANE)
+    n_pad = _round_up(N, _SUBLANE)
+    be_eff = min(be, _round_up(E + 1, _LANE))
+    return (
+        2 * (m_pad + n_pad) * r_pad * 4          # U/W + g accumulators
+        + be_eff * (m_pad + n_pad + be_eff) * 4  # one-hots + scan triangle
+        + max(m_pad, n_pad) * be_eff * 4         # boundary-difference matrix
+    )
 
 
 def _pad_rows(a, target):
@@ -80,8 +100,9 @@ def sddmm_factor_grad(
 
     vmem = 2 * (m_pad + n_pad) * r_pad * 4 + be_eff * (m_pad + n_pad) * 4
     if vmem > _MAX_VMEM_BYTES and not force_kernel:
-        # resident one-hot layout does not fit — gather fallback is the
-        # nnz-proportional-FLOPs path and XLA handles it well.
+        # resident one-hot layout does not fit: the XLA scatter path
+        obs.counter("kernel_fallbacks_total", kernel="sddmm_scatter",
+                    reason="vmem").inc()
         return sddmm_factor_grad_ref(entries, u, w)
 
     def pad_e(a, fill):
@@ -140,14 +161,11 @@ def sddmm_segment_grad(
     # boundary lane exists: pad at least one slot past E.
     e_pad = _round_up(E + 1, be_eff)
 
-    vmem = (
-        2 * (m_pad + n_pad) * r_pad * 4          # U/W + g accumulators
-        + be_eff * (m_pad + n_pad + be_eff) * 4  # one-hots + scan triangle
-        + max(m_pad, n_pad) * be_eff * 4         # boundary-difference matrix
-    )
-    if vmem > _MAX_VMEM_BYTES and not force_kernel:
-        # resident layout does not fit — the XLA segment path is the
-        # nnz-proportional fallback and already beats scatter on CPU.
+    if segment_vmem_bytes(M, N, r, E, be) > _MAX_VMEM_BYTES \
+            and not force_kernel:
+        # resident layout does not fit: the XLA segment path
+        obs.counter("kernel_fallbacks_total", kernel="sddmm_segment",
+                    reason="vmem").inc()
         return sddmm_segment_grad_ref(entries, u, w, chunk=chunk)
 
     def pad_e(a, fill):

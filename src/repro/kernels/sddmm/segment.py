@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -60,7 +61,10 @@ def segment_reduce(contrib, ptr, chunk: int = SEG_CHUNK):
     base = jnp.take(cpre, ci, axis=0, indices_are_sorted=True, mode="clip")
     sel = jnp.take(ch, ci, axis=0, indices_are_sorted=True, mode="clip")
     tri = jnp.take(jnp.asarray(_tri(chunk)), ofs, axis=0, mode="clip")
-    s = base + jnp.einsum("bc,bcr->br", tri, sel)  # prefix at each boundary
+    # the 0/1 selection must not round ``sel``: at the TPU's default
+    # precision the matmul would take its f32 operands as bf16
+    s = base + jnp.einsum("bc,bcr->br", tri, sel,  # prefix at each boundary
+                          precision=jax.lax.Precision.HIGHEST)
     return s[1:] - s[:-1]
 
 

@@ -42,8 +42,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_compiler_params
-
+# every matmul here multiplies a 0/1 matrix by f32 values: at full f32
+# precision it gathers or sums them to f32 accuracy, where one bf16 pass
+# (the TPU's default) would keep 8 bits of each value
+_EXACT = jax.lax.Precision.HIGHEST
 
 def _make_kernel(side: str):
     def _kernel(rows_ref, cols_ref, vals_ref, valid_ref, lo_ref, hi_ref,
@@ -71,11 +73,11 @@ def _make_kernel(side: str):
                 ).astype(jnp.float32)               # (be, N)
         ue = jax.lax.dot_general(                   # gather U[rows]: (be, r)
             oh_r, u, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=_EXACT, preferred_element_type=jnp.float32,
         )
         we = jax.lax.dot_general(                   # gather W[cols]: (be, r)
             oh_c, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=_EXACT, preferred_element_type=jnp.float32,
         )
         e = valid * (vals - jnp.sum(ue * we, axis=1))       # (be,)
         loss_ref[0, 0] += jnp.sum(e * e)
@@ -89,7 +91,7 @@ def _make_kernel(side: str):
         tri = (jj < ii).astype(jnp.float32)
         prefix = carry_ref[0:1, :] + jax.lax.dot_general(
             tri, c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=_EXACT, preferred_element_type=jnp.float32,
         )                                           # (be, r): S at each lane
 
         # boundary-difference accumulation: row s of D is +1 at hi[s]'s lane
@@ -102,7 +104,7 @@ def _make_kernel(side: str):
                  - (lo[:, None] == pos).astype(jnp.float32))    # (S, be)
         g_ref[...] += jax.lax.dot_general(
             d_sel, prefix, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=_EXACT, preferred_element_type=jnp.float32,
         )
         carry_ref[0:1, :] += jnp.sum(c, axis=0, keepdims=True)
 
@@ -148,7 +150,7 @@ def sddmm_segment_grad_pallas(rows, cols, vals, valid, lo, hi, u, w, *,
         scratch_shapes=[
             pltpu.VMEM((8, r), jnp.float32),              # running prefix carry
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -11,7 +11,7 @@ entries, and its slice of the item axis*:
 
 ``MeshPlan`` collapses all of it into one immutable object:
 
-    plan = MeshPlan.build(p=4, q=4, mesh=make_mesh((2, 2), ("data", "model")))
+    plan = MeshPlan.build(p=4, q=4, mesh=build_mesh((2, 2), ("data", "model")))
     plan.owner(1, 3)          # -> the Device owning block (1, 3)
     plan.entries_spec()       # -> SparseProblem pytree of PartitionSpecs
     plan.factor_spec          # -> P(row_axes, col_axes) for U/W stacks
@@ -39,16 +39,16 @@ from typing import Any, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.compat import make_mesh
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 
 def build_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """Construct a device mesh (the one mesh-construction call in the
-    repo; ``launch/mesh.py`` delegates here)."""
+    """Construct a device mesh with Auto axis types (the one
+    mesh-construction call in the repo; ``launch/mesh.py`` delegates
+    here)."""
 
-    return make_mesh(tuple(axis_shapes), tuple(axis_names))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def _as_axes(axes) -> Tuple[str, ...]:

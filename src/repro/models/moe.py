@@ -23,7 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as _axis_size, shard_map as _shard_map
 from repro.config import MoEConfig
 from repro.models import layers as L
 
@@ -118,7 +117,7 @@ def _moe_local(params, x, cfg: MoEConfig, ep_axis: str | None,
         y = _moe_partial(params, xt, top_idx, top_w, 0, Ep, Ep)
     else:
         rank = jax.lax.axis_index(ep_axis)
-        n_ranks = _axis_size(ep_axis)
+        n_ranks = jax.lax.axis_size(ep_axis)
         Ep_global = Ep * n_ranks                           # params arrive pre-sliced
         y = _moe_partial(params, xt, top_idx, top_w, rank * Ep, Ep, Ep_global)
         y = jax.lax.psum(y, ep_axis)
@@ -147,7 +146,7 @@ def _moe_a2a(params, x, cfg: MoEConfig, ep_axis: str, aux_axes,
     xt = x.reshape(-1, d)
     t = xt.shape[0]
     k = cfg.num_experts_per_tok
-    n = _axis_size(ep_axis)
+    n = jax.lax.axis_size(ep_axis)
     Ep_local = params["wi_gate"].shape[0]
     C = max(1, int(t * k / n * cap_factor))
 
@@ -231,7 +230,7 @@ def moe_ffn(params, x, cfg: MoEConfig, *, ep_axis: str | None = None,
 
     if impl == "a2a":
         xspec = P(dp, ep, None)                        # sequence over EP
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda p, xx: _moe_a2a(p, xx, cfg, ep, aux_axes,
                                    a2a_capacity_factor),
             mesh=mesh, in_specs=(pspec, xspec), out_specs=(xspec, P()),
@@ -240,7 +239,7 @@ def moe_ffn(params, x, cfg: MoEConfig, *, ep_axis: str | None = None,
         return fn(params, x)
 
     xspec = P(dp, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda p, xx: _moe_local(p, xx, cfg, ep, aux_axes),
         mesh=mesh, in_specs=(pspec, xspec), out_specs=(xspec, P()),
         check_vma=False,

@@ -130,14 +130,10 @@ def trace(log_dir: str):
     ``log_dir`` (``jax.profiler.trace``); spans entered with
     ``annotate=True`` show up as named slices.  Load the
     ``*.trace.json.gz`` under ``log_dir/plugins/profile/*/`` in
-    https://ui.perfetto.dev.  Degrades to a no-op when the profiler is
-    unavailable (e.g. stripped-down CI images)."""
+    https://ui.perfetto.dev.  A profiler that cannot start raises: a
+    run asked to be traced never silently yields no trace."""
 
-    try:
-        import jax
+    import jax
 
-        ctx = jax.profiler.trace(str(log_dir))
-    except Exception:
-        ctx = contextlib.nullcontext()
-    with ctx:
+    with jax.profiler.trace(str(log_dir)):
         yield

@@ -51,13 +51,16 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
-from repro.compat import shard_map
 from repro.core.assemble import assemble
 from repro.core.grid import GridSpec
 from repro.kernels.quant import dequant_score
 from repro.serve.quant import QuantizedRecommendIndex, quantize_index
 
 _SEEN_PAD_QUANTUM = 16
+# f32 scores are computed at full f32 precision: the TPU's default matmul
+# rounds f32 operands to bf16, which reorders a top-100 against the f32
+# reference (on the CPU the two settings give identical results)
+_F32_SCORES = jax.lax.Precision.HIGHEST
 
 
 class RecommendIndex(NamedTuple):
@@ -175,7 +178,7 @@ def _batch_scores(index, user_ids, method):
             index.u_q[user_ids], index.u_scale[user_ids],
             index.w_q, index.w_scale, method=method,
         )
-    return index.u[user_ids] @ index.w.T
+    return jnp.matmul(index.u[user_ids], index.w.T, precision=_F32_SCORES)
 
 
 @partial(jax.jit, static_argnames=("k", "exclude_seen", "method"))
@@ -405,12 +408,13 @@ def _make_sharded_topk(plan, k: int, exclude_seen: bool, num_items: int,
     else:
         def body(u, w_local, seen, user_ids):
             start = jax.lax.axis_index(ax) * shard_items
-            scores = u[user_ids] @ w_local.T                 # (B, ln)
+            scores = jnp.matmul(u[user_ids], w_local.T,      # (B, ln)
+                                precision=_F32_SCORES)
             return select_merge(scores, start, seen, user_ids)
 
         in_specs = (P(), plan.item_spec, P(), P())
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=in_specs,
         out_specs=(P(), P()),

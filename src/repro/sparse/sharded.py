@@ -39,7 +39,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
-from repro.compat import shard_map
 from repro.mesh.plan import MeshPlan
 from repro.sparse import store as store_mod
 from repro.sparse.store import (
@@ -372,7 +371,7 @@ def _make_shard_sampler(plan: MeshPlan, batch: int, E: int, mb: int, nb: int):
         return store_mod._assemble_batch(parts, bpr, bpc, batch, mb, nb,
                                          spl.nnz)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=plan.mesh,
         in_specs=(espec, plan.grid_spec, P()),
         out_specs=espec,
@@ -420,7 +419,7 @@ def _make_shard_grads(plan: MeshPlan, use_kernel: bool, method: str,
         ))(spl.entries, U, W)
         return gu, gw
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=plan.mesh, in_specs=(espec, g, g), out_specs=(g, g),
         check_vma=False,
     ))

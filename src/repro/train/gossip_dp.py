@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core import compress as C
 from repro.optim import Optimizer
 from repro.optim.optimizers import apply_updates
@@ -103,7 +102,7 @@ def make_gossip_dp_step(
 
     pstacked = P(axis)
     step = jax.jit(
-        _shard_map(
+        jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(pstacked, pstacked, P(axis), P()),
             out_specs=(pstacked, pstacked, P()),

@@ -31,7 +31,7 @@ def run_prog(prog: str, devices: int = 8, timeout: int = 420) -> str:
 def test_gossip_mc_distributed_matches_single_device():
     run_prog("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.mesh import build_mesh as make_mesh
 from repro.config import GossipMCConfig
 from repro.core import grid as G, gossip, waves, objective as obj
 from repro.core.state import make_problem, init_state
@@ -60,7 +60,7 @@ print("OK", diff)
 def test_gossip_mc_sparse_layout_matches_dense_full_gd():
     run_prog("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.mesh import build_mesh as make_mesh
 from repro.config import GossipMCConfig
 from repro.core import grid as G, gossip, waves, objective as obj
 from repro.core.state import make_problem, init_state
@@ -92,7 +92,7 @@ print("OK", diff)
 def test_gossip_mc_staleness_and_compression_still_converge():
     run_prog("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.mesh import build_mesh as make_mesh
 from repro.config import GossipMCConfig
 from repro.core import grid as G, gossip
 from repro.core.state import make_problem, init_state
@@ -119,7 +119,7 @@ print("OK", base)
 def test_gossip_dp_lm_training_matches_allreduce():
     run_prog("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
+from repro.mesh import build_mesh as make_mesh
 from repro.config import get_smoke_config, TrainConfig
 from repro.models import build_model
 from repro.models.api import Ctx
@@ -168,7 +168,7 @@ def test_moe_ep_matches_single_program():
     run_prog("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.mesh import build_mesh as make_mesh
 from repro.config import MoEConfig
 from repro.models import moe as MOE
 cfg = MoEConfig(num_experts=8, num_experts_per_tok=2, expert_d_ff=32)
@@ -192,7 +192,7 @@ def test_moe_a2a_dispatch_matches_single_program():
     run_prog("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import make_mesh
+from repro.mesh import build_mesh as make_mesh
 from repro.config import MoEConfig
 from repro.models import moe as MOE
 cfg = MoEConfig(num_experts=8, num_experts_per_tok=2, expert_d_ff=32)
@@ -220,7 +220,7 @@ print("OK frac_off", frac_off)
 def test_train_step_multipod_mesh_runs_and_improves():
     run_prog("""
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from repro.mesh import build_mesh as make_mesh
 from repro.config import get_smoke_config, ShapeConfig, TrainConfig
 from repro.models import build_model
 from repro.models.api import Ctx

@@ -71,7 +71,7 @@ def test_gossip_schedule_matches_direct_step_loop(setup, layout):
     """Gossip schedule (1×1 degenerate mesh on CPU) == hand-rolled
     make_gossip_step loop == FullGD, within 1e-5."""
 
-    from repro.compat import make_mesh
+    from repro.mesh import build_mesh as make_mesh
     from repro.core.state import init_state
 
     ds, cfg, problems = setup

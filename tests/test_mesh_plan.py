@@ -222,14 +222,19 @@ for f in sp_ref.entries._fields:
                                   np.asarray(getattr(ref2.entries, f)))
 np.testing.assert_array_equal(np.asarray(sh2.sp.nnz), np.asarray(ref2.nnz))
 
-# shard-local f-gradients == global vmap (exact: block-local math)
+# shard-local f-gradients == global vmap.  The math is block-local, but
+# XLA vectorizes the segment sums differently for a device's 2x2 blocks
+# than for the global 4x4 batch, so the f32 sums round in another order:
+# allow 8 ulps of the largest gradient (about 4 ulps are observed)
 U = jnp.asarray(rng.normal(size=(p, q, M // p, r)), jnp.float32)
 W = jnp.asarray(rng.normal(size=(p, q, N // q, r)), jnp.float32)
 gu, gw = f_grads_sharded(sh2, U, W)
 _, gu0, gw0 = jax.vmap(jax.vmap(lambda e, u, w: f_grads_sparse(e, u, w)))(
     ref2.entries, U, W)
-assert float(jnp.max(jnp.abs(gu - gu0))) <= 1e-5
-assert float(jnp.max(jnp.abs(gw - gw0))) <= 1e-5
+eps = float(np.finfo(np.float32).eps)
+for g, g0 in ((gu, gu0), (gw, gw0)):
+    tol = 8 * eps * float(jnp.max(jnp.abs(g0)))
+    assert float(jnp.max(jnp.abs(g - g0))) <= tol, (float(jnp.max(jnp.abs(g - g0))), tol)
 print("OK")
 """)
 

@@ -9,7 +9,7 @@ The contracts pinned here:
   pure per-row map — quantize-then-slice == slice-then-quantize, which is
   why the sharded path serves int8 with zero extra machinery;
 * ``method="dequant"`` equals the numpy dequantize-then-matmul oracle
-  exactly; ``method="fused"`` (XLA emulation) equals the Pallas kernel in
+  within the f32 dot's rounding bound; ``method="fused"`` (XLA emulation) equals the Pallas kernel in
   interpret mode **bit for bit** (both accumulate the int8 products in
   int32, then apply the same f32 epilogue);
 * top-k overlap@k against the f32 index stays ≥ 0.99 on randomized grids
@@ -123,7 +123,11 @@ def test_dequant_method_equals_numpy_oracle():
                         method="dequant")
     u = np.asarray(q.u_q[:32], np.float32) * np.asarray(q.u_scale[:32])[:, None]
     w = np.asarray(q.w_q, np.float32) * np.asarray(q.w_scale)[:, None]
-    np.testing.assert_array_equal(np.asarray(got), u @ w.T)
+    # same dequantized rows, but XLA's and numpy's f32 dots sum the r
+    # products in different orders: each is within r·eps·Σ|u_i·w_i| of the
+    # exact dot (the standard bound), so they differ by at most twice that
+    bound = 2 * u.shape[1] * np.finfo(np.float32).eps * (np.abs(u) @ np.abs(w).T)
+    assert (np.abs(np.asarray(got) - u @ w.T) <= bound).all()
 
 
 def test_fused_xla_equals_pallas_kernel_bitwise():

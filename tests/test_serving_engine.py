@@ -3,9 +3,10 @@ under load, RefreshPolicy auto-refit, and shutdown semantics.
 
 The contracts pinned here (DESIGN.md §14):
 
-* every request size routes onto the ladder and comes back **bit-identical**
-  to the direct jitted ``recommend_topk`` — padding and chunking are
-  invisible;
+* every request size routes onto the ladder and comes back equal to the
+  direct jitted ``recommend_topk`` — item ids exact wherever scores are not
+  tied, scores up to the f32 rounding of a matmul at another batch shape;
+  padding and chunking are otherwise invisible;
 * ``serve_compiles_total`` equals the bucket count after startup and
   never moves under traffic or refresh (the always-hot property);
 * a request runs against exactly one factor version even when a refresh
@@ -41,6 +42,22 @@ def _oracle(idx, user_ids, k=K):
     items, scores = recommend_topk(idx, jnp.asarray(user_ids, jnp.int32),
                                    k=k, exclude_seen=True)
     return np.asarray(items), np.asarray(scores)
+
+
+def _assert_same_topk(items, scores, ref_i, ref_s, rtol=1e-5):
+    """Top-k equal up to f32 rounding.  A request padded into a bucket
+    runs the score matmul at another batch shape than the direct call, and
+    XLA picks its reduction order per shape, so scores may differ in the
+    last ulps.  Item ids must match exactly wherever a score is not tied
+    (within ``rtol``) with a neighbour, where rounding may swap them."""
+
+    atol = rtol * np.abs(ref_s).max()
+    np.testing.assert_allclose(scores, ref_s, rtol=rtol, atol=atol)
+    close = np.isclose(ref_s[:, 1:], ref_s[:, :-1], rtol=rtol, atol=atol)
+    tied = np.zeros(ref_s.shape, bool)
+    tied[:, 1:] |= close
+    tied[:, :-1] |= close
+    np.testing.assert_array_equal(items[~tied], ref_i[~tied])
 
 
 # --------------------------------------------------------------------------
@@ -121,9 +138,7 @@ def test_engine_routing_parity_every_size():
         for sz in sizes:
             users = rng.integers(0, 120, size=sz).astype(np.int32)
             items, scores = eng.recommend(users)
-            ref_i, ref_s = _oracle(idx, users)
-            np.testing.assert_array_equal(items, ref_i)
-            assert np.array_equal(scores, ref_s)
+            _assert_same_topk(items, scores, *_oracle(idx, users))
         assert obs.counter("serve_compiles_total").value == 3.0
         m = eng.metrics()
         assert m["compiles"] == 3.0

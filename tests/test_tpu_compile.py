@@ -1,0 +1,237 @@
+"""Compile rehearsal for the TPU v5e, without a chip.
+
+The TPU compiler is installed even where no chip is attached, so the main
+path's kernels and jitted steps are compiled here for a described
+``v5e:2x2`` topology at the widths ``chip_smoke.py`` runs (MovieLens-1M
+shape, rank 32).  A compile that the chip's compiler would refuse (a tile
+not aligned to the layout, more VMEM than a kernel may use, a program that
+does not fit HBM) fails here, at no chip time.  Nothing runs: these tests
+say nothing about results or speed.
+
+The topology is described inside a module fixture, never at import, so
+every pytest worker collects the same tests and only the worker running
+this file loads the TPU library.
+"""
+
+import importlib.util
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+from repro.config import GossipMCConfig
+from repro.core import gossip, waves
+from repro.core.state import State
+from repro.kernels.quant.kernel import dequant_score_pallas
+from repro.kernels.sddmm.ops import _MAX_VMEM_BYTES, segment_vmem_bytes
+from repro.kernels.sddmm.segment_kernel import sddmm_segment_grad_pallas
+from repro.mesh import MeshPlan
+from repro.sparse.entries import BlockEntries
+from repro.sparse.store import DEFAULT_BUCKET, SparseProblem, bucketed_capacity
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM_BYTES = 16 * 2**30          # one v5e chip
+LANE, BE = 128, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back here: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def ml1m():
+    """The smoke's deployment: its size, ratings and append headroom."""
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(_ROOT, "chip_smoke.py"))
+    cs = sys.modules["chip_smoke"] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cs)
+    size = cs.ML1M
+    ds, _, _, headroom = cs.deployment(size, seed=0)
+    rows, cols = np.nonzero(ds.train_mask)
+
+    def block(g, headroom=0):
+        """(mb, nb, capacity) of the sparse store on a g x g grid."""
+
+        mb, nb = -(-size.users // g), -(-size.items // g)
+        nnz = np.bincount(rows // mb * g + cols // nb, minlength=g * g)
+        return mb, nb, bucketed_capacity(int(nnz.max()), DEFAULT_BUCKET,
+                                         headroom)
+
+    return size, block, headroom
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _store(p, q, mb, nb, E, sharding):
+    """SparseProblem of shapes: every leaf stacked over the (p, q) grid."""
+
+    i32, f32 = jnp.int32, jnp.float32
+    grid = (p, q)
+    return SparseProblem(
+        BlockEntries(
+            rows=_sds(grid + (E,), i32, sharding),
+            cols=_sds(grid + (E,), i32, sharding),
+            vals=_sds(grid + (E,), f32, sharding),
+            valid=_sds(grid + (E,), f32, sharding),
+            col_perm=_sds(grid + (E,), i32, sharding),
+            row_ptr=_sds(grid + (mb + 1,), i32, sharding),
+            col_ptr=_sds(grid + (nb + 1,), i32, sharding),
+        ),
+        _sds(grid, i32, sharding),
+    )
+
+
+def _state(p, q, mb, nb, r, sharding):
+    f32 = jnp.float32
+    return State(_sds((p, q, mb, r), f32, sharding),
+                 _sds((p, q, nb, r), f32, sharding),
+                 _sds((), jnp.int32, sharding))
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+@pytest.mark.parametrize("side", ["u", "w"])
+def test_segment_kernel_compiles_at_largest_admitted_block(topo, one_chip,
+                                                           ml1m, side):
+    """The largest ML-1M block the VMEM guard in ``kernels/sddmm/ops.py``
+    lets through to the kernel compiles for the chip: the guard's estimate
+    is not below what Mosaic needs."""
+
+    size, block, _ = ml1m
+    admitted = [(g, *block(g)) for g in range(1, 17)
+                if segment_vmem_bytes(*block(g)[:2], size.rank,
+                                      block(g)[2]) <= _MAX_VMEM_BYTES]
+    g, mb, nb, E = admitted[0]                  # smallest grid = largest block
+    m_pad, n_pad = _round_up(mb, 8), _round_up(nb, 8)
+    e_pad = _round_up(E + 1, BE)
+    s = m_pad if side == "u" else n_pad
+    i32, f32 = jnp.int32, jnp.float32
+    args = [_sds((1, e_pad), dt, one_chip) for dt in (i32, i32, f32, f32)]
+    args += [_sds((1, s), i32, one_chip)] * 2
+    args += [_sds((m_pad, LANE), f32, one_chip),
+             _sds((n_pad, LANE), f32, one_chip)]
+    compiled = sddmm_segment_grad_pallas.lower(
+        *args, side=side, be=BE, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert g <= size.kernel_grid, (g, mb, nb)
+
+
+def test_int8_score_kernel_compiles_at_top_bucket(one_chip, ml1m):
+    size, _, _ = ml1m
+    b = size.buckets[-1]
+    bn = 512
+    n_pad = _round_up(size.items, bn)
+    i8, f32 = jnp.int8, jnp.float32
+    compiled = dequant_score_pallas.lower(
+        _sds((b, LANE), i8, one_chip), _sds((b, 1), f32, one_chip),
+        _sds((n_pad, LANE), i8, one_chip), _sds((1, n_pad), f32, one_chip),
+        bn=bn, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _wave_tables(p, q, sharding):
+    tables = max(waves.wave_tables(p, q), key=lambda t: t.blocks.shape[0])
+    return jax.tree.map(lambda x: _sds(x.shape, x.dtype, sharding), tables)
+
+
+def test_wave_step_fits_one_chip_at_ml1m(one_chip, ml1m):
+    """The smoke's training step (XLA segment path, 4x4 grid, the store
+    with its append headroom) compiles and fits one chip's HBM."""
+
+    size, block, headroom = ml1m
+    g = size.grid
+    mb, nb, E = block(g, headroom)
+    compiled = waves.wave_step.lower(
+        _store(g, g, mb, nb, E, one_chip),
+        _state(g, g, mb, nb, size.rank, one_chip),
+        _wave_tables(g, g, one_chip),
+        rho=1e2, lam=1e-6, a=1e-3, b=5e-7,
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_wave_step_with_kernel_compiles_at_kernel_grid(one_chip, ml1m,
+                                                      monkeypatch):
+    """The smoke's ``use_kernel=True`` fit: the vmapped segment kernel
+    inside the wave step compiles to Mosaic on the kernel grid."""
+
+    # the kernel wrappers pick interpret mode off-TPU from the default
+    # backend, which is the CPU here: steer them to the chip's branch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    size, block, _ = ml1m
+    g = size.kernel_grid
+    mb, nb, E = block(g)
+    compiled = waves.wave_step.lower(
+        _store(g, g, mb, nb, E, one_chip),
+        _state(g, g, mb, nb, size.rank, one_chip),
+        _wave_tables(g, g, one_chip),
+        rho=1e2, lam=1e-6, a=1e-3, b=5e-7, use_kernel=True,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_gossip_step_compiles_for_four_chips(topo, ml1m):
+    """``chip_smoke.py --chips 4``: gossip rounds on a 2x2 mesh of
+    described chips, 2x2 blocks per chip, with the halo collectives."""
+
+    size, block, _ = ml1m
+    g = size.grid
+    mb, nb, E = block(g)
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2),
+                ("data", "model"))
+    plan = MeshPlan.build(g, g, mesh=mesh)
+    cfg = GossipMCConfig(m=mb * g, n=nb * g, p=g, q=g, rank=size.rank,
+                         rho=1e2, lam=1e-6, a=1e-3, b=5e-7)
+    step, (problem_spec, carry_spec) = gossip.make_gossip_step(
+        None, (g, g), cfg, plan=plan, steps_per_call=size.gossip_rounds,
+        layout="sparse")
+    placed = lambda tree, specs: jax.tree.map(  # noqa: E731
+        lambda x, s: _sds(x.shape, x.dtype, NamedSharding(mesh, s)),
+        tree, specs)
+    store = _store(g, g, mb, nb, E, None)
+    carry = jax.eval_shape(gossip.init_carry,
+                           _state(g, g, mb, nb, size.rank, None))
+    compiled = step.lower(placed(store, problem_spec),
+                          placed(carry, carry_spec)).compile()
+    text = compiled.as_text()
+    assert "collective-permute" in text
+    assert _device_bytes(compiled) < HBM_BYTES

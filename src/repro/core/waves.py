@@ -15,6 +15,11 @@ and is what the distributed gossip step (gossip.py) computes per device tile.
 The supported session entry point is ``repro.mc.Trainer.fit(problem,
 schedule="wave" | "full")`` — the module-level :func:`fit` is a deprecated
 shim over the same internal loop (:func:`_fit`).
+
+The loop is annotated for a profiler capture (``obs.trace``): each
+round's body is the span ``fit.round``, the wave order's draw and host
+read inside it ``fit.order``, and each eval boundary's cost ``fit.cost``.
+None declares outputs, so the spans add no device sync.
 """
 
 from __future__ import annotations
@@ -27,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import GossipMCConfig
 from repro.core import grid as G
 from repro.core import objective as obj
@@ -210,8 +216,10 @@ def _fit(
                 rho=cfg.rho, lam=cfg.lam, a=cfg.a, b=cfg.b,
                 use_kernel=use_kernel, method=method, chunk=chunk,
             )
-        order = jax.random.permutation(key, len(tables))
-        order = np.asarray(order)  # static python order; reshuffled per round
+        # static python order, reshuffled per round; the host read blocks
+        # until the device has computed the permutation
+        with obs.span("fit.order", annotate=True):
+            order = np.asarray(jax.random.permutation(key, len(tables)))
         for w in order:
             state = wave_step(
                 problem, state, tables[int(w)],
@@ -221,10 +229,13 @@ def _fit(
         return state
 
     for rd in range(start_round, num_rounds):
-        key, rk = jax.random.split(key)
-        state = one_round(state, rk)
+        with obs.span("fit.round", annotate=True):
+            key, rk = jax.random.split(key)
+            state = one_round(state, rk)
         if (rd + 1) % eval_every == 0 or rd == num_rounds - 1:
-            cost = float(obj.total_cost(problem, state.U, state.W, cfg.lam))
+            with obs.span("fit.cost", annotate=True):
+                cost = float(obj.total_cost(problem, state.U, state.W,
+                                            cfg.lam))
             history.append((int(state.t), cost))
             if callback:
                 callback(int(state.t), cost)

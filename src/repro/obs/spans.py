@@ -15,14 +15,17 @@ primitive that gets it right:
     sp.seconds       # device-true: clock stops after block_until_ready
     sp.host_seconds  # dispatch-only wall, for async-depth diagnosis
 
-Both times land in the default registry as histograms
-(``span_seconds{name=...}`` and ``span_host_seconds{name=...}``), so any
-snapshot carries p50/p99 per region.  ``annotate=True`` additionally wraps
-the region in ``jax.profiler.TraceAnnotation`` so spans line up by name in
-a Perfetto trace captured via :func:`trace`:
+``seconds`` lands in the default registry as the histogram
+``span_seconds{name=...}``, so any snapshot carries p50/p99 per region.
+``annotate=True`` additionally wraps the region in
+``jax.profiler.TraceAnnotation`` so spans line up by name in a Perfetto
+trace captured via :func:`trace`; keyword metadata rides along as the
+event's stats (the name stays plain, so matching by name still works):
 
     with obs.trace("/tmp/trace"):           # then: perfetto ui, load the
         with obs.span("fit", annotate=True) as sp:   # .trace.json.gz
+            ...
+        with obs.span("serve.request", annotate=True, rid=7):
             ...
 
 ``device_sync`` is the exported sync primitive (``BenchLogger`` uses it so
@@ -68,14 +71,15 @@ class Span:
     span degrades to host wall-clock (still recorded; ``host_seconds ==
     seconds``)."""
 
-    __slots__ = ("name", "registry", "annotate", "_outputs", "_t0",
+    __slots__ = ("name", "registry", "annotate", "meta", "_outputs", "_t0",
                  "host_seconds", "seconds", "_annotation")
 
     def __init__(self, name: str, registry: Optional[_reg.Registry] = None,
-                 annotate: bool = False):
+                 annotate: bool = False, **meta: Any):
         self.name = name
         self.registry = registry if registry is not None else _reg.get_registry()
         self.annotate = annotate
+        self.meta = meta
         self._outputs: Any = None
         self._annotation = None
         self.host_seconds: Optional[float] = None
@@ -96,7 +100,8 @@ class Span:
             try:
                 import jax
 
-                self._annotation = jax.profiler.TraceAnnotation(self.name)
+                self._annotation = jax.profiler.TraceAnnotation(
+                    self.name, **self.meta)
                 self._annotation.__enter__()
             except Exception:       # profiler unavailable: time anyway
                 self._annotation = None
@@ -113,15 +118,15 @@ class Span:
         if exc_type is None and self.registry.enabled:
             self.registry.histogram(
                 "span_seconds", name=self.name).observe(self.seconds)
-            self.registry.histogram(
-                "span_host_seconds", name=self.name).observe(self.host_seconds)
 
 
 def span(name: str, registry: Optional[_reg.Registry] = None,
-         annotate: bool = False) -> Span:
-    """Context manager: a named, registry-recorded, device-true timer."""
+         annotate: bool = False, **meta: Any) -> Span:
+    """Context manager: a named, registry-recorded, device-true timer.
+    ``meta`` (e.g. ``rid=7``) is attached to the trace event when
+    ``annotate=True``; the registry keys on ``name`` alone."""
 
-    return Span(name, registry=registry, annotate=annotate)
+    return Span(name, registry=registry, annotate=annotate, **meta)
 
 
 @contextlib.contextmanager

@@ -223,16 +223,21 @@ class ServingEngine:
         if self._t_first is None:
             self._t_first = time.perf_counter()
         for start, length, bucket in self.ladder.plan(n):
-            t0 = time.perf_counter()
-            chunk = user_ids[start : start + length]
-            if length < bucket:
-                chunk = np.pad(chunk, (0, bucket - length))
-            items, scores = self._execs[bucket](bufs, chunk)
+            # host side of the chunk: pad + the executable call (async)
+            with obs.span("serve.dispatch", annotate=True, rid=req.rid,
+                          bucket=bucket) as dispatch:
+                chunk = user_ids[start : start + length]
+                if length < bucket:
+                    chunk = np.pad(chunk, (0, bucket - length))
+                items, scores = self._execs[bucket](bufs, chunk)
             # host copies force the device sync → device-true batch stamp
-            out_items[start : start + length] = np.asarray(items)[:length]
-            out_scores[start : start + length] = np.asarray(scores)[:length]
+            with obs.span("serve.fetch", annotate=True, rid=req.rid,
+                          bucket=bucket) as fetch:
+                rows = slice(start, start + length)
+                out_items[rows] = np.asarray(items)[:length]
+                out_scores[rows] = np.asarray(scores)[:length]
             obs.histogram("serve_batch_seconds", bucket=str(bucket)).observe(
-                time.perf_counter() - t0
+                dispatch.seconds + fetch.seconds
             )
             obs.counter("engine_batches_total").inc()
         obs.histogram("serve_request_seconds").observe(

@@ -8,7 +8,11 @@ the (items, scores) arrays.  The queue is the engine's backpressure
 surface — its depth is exported live as the ``serve_queue_depth`` gauge,
 and the time a request spends waiting in it lands in the
 ``queue_wait_seconds`` histogram, kept strictly separate from the
-on-device ``serve_batch_seconds`` (DESIGN.md §14).
+on-device ``serve_batch_seconds`` (DESIGN.md §14).  Each request carries
+an integer id (``rid``); the worker wraps it, from the moment it takes
+the request up to its future's resolution, in the annotated span
+``serve.request`` with that id as metadata, so a profiler capture ties
+the request to its chunks (``serve.dispatch``/``serve.fetch``).
 
 Shutdown semantics: ``close()`` rejects new submissions;
 ``drain()`` blocks until everything already enqueued has resolved;
@@ -19,6 +23,7 @@ nothing ever hangs silently.
 
 from __future__ import annotations
 
+import itertools
 import queue as _queue
 import threading
 import time
@@ -33,10 +38,11 @@ from repro import obs
 class Request:
     """One in-flight serving request."""
 
-    __slots__ = ("user_ids", "future", "t_submit")
+    __slots__ = ("user_ids", "rid", "future", "t_submit")
 
-    def __init__(self, user_ids: np.ndarray):
+    def __init__(self, user_ids: np.ndarray, rid: int):
         self.user_ids = user_ids
+        self.rid = rid
         self.future: Future = Future()
         self.t_submit = time.perf_counter()
 
@@ -57,6 +63,7 @@ class ServeWorker:
         self._q: _queue.Queue = _queue.Queue()
         self._closed = False
         self._lock = threading.Lock()
+        self._rids = itertools.count()
         self._depth = obs.gauge("serve_queue_depth")
         self._thread = threading.Thread(target=self._loop, name=name,
                                         daemon=True)
@@ -67,12 +74,12 @@ class ServeWorker:
     # ------------------------------------------------------------------ #
 
     def submit(self, user_ids: np.ndarray) -> Future:
-        req = Request(user_ids)
         with self._lock:
             if self._closed:
                 raise RuntimeError(
                     "serving engine is shut down; no new requests accepted"
                 )
+            req = Request(user_ids, next(self._rids))
             self._q.put(req)
         self._depth.set(self._q.qsize())
         return req.future
@@ -93,13 +100,14 @@ class ServeWorker:
                     return
                 if not item.future.set_running_or_notify_cancel():
                     continue          # cancelled while queued
-                obs.histogram("queue_wait_seconds").observe(
-                    time.perf_counter() - item.t_submit
-                )
-                try:
-                    item.future.set_result(self._execute(item))
-                except Exception as err:  # surface, never kill the worker
-                    item.future.set_exception(err)
+                with obs.span("serve.request", annotate=True, rid=item.rid):
+                    obs.histogram("queue_wait_seconds").observe(
+                        time.perf_counter() - item.t_submit
+                    )
+                    try:
+                        item.future.set_result(self._execute(item))
+                    except Exception as err:  # surface, never kill the worker
+                        item.future.set_exception(err)
             finally:
                 self._q.task_done()
                 self._depth.set(self._q.qsize())

@@ -210,6 +210,54 @@ def test_device_sync_handles_non_arrays():
     obs.device_sync({"a": jnp.ones(3), "b": [1, 2.5, None]})
 
 
+def test_span_records_one_histogram_per_name():
+    """``seconds`` is recorded; the dispatch-only ``host_seconds`` stays
+    an attribute and no longer lands in a second histogram."""
+
+    with obs.span("one", annotate=True, rid=3) as sp:
+        sp.outputs(jnp.ones(4) * 2)
+    assert sp.host_seconds is not None and sp.seconds >= sp.host_seconds
+    hists = obs.snapshot()["histograms"]
+    assert hists["span_seconds{name=one}"]["count"] == 1
+    assert not any(k.startswith("span_host_seconds") for k in hists)
+
+
+def _host_events(log_dir, prefix):
+    """(name, {stat: value}) of every host trace event named ``prefix*``
+    in the one capture under ``log_dir``."""
+
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefix):
+                    out.append((e.name, dict(e.stats)))
+    return out
+
+
+def test_span_meta_rides_the_trace_event(tmp_path):
+    """Keyword metadata becomes stats of the trace event; the event keeps
+    the plain name, and the registry keys on the name alone."""
+
+    with obs.trace(str(tmp_path)):
+        for rid in (4, 5):
+            with obs.span("meta.demo", annotate=True, rid=rid, bucket=64):
+                pass
+    events = _host_events(tmp_path, "meta.")
+    assert sorted(events, key=lambda e: e[1]["rid"]) == [
+        ("meta.demo", {"rid": 4, "bucket": 64}),
+        ("meta.demo", {"rid": 5, "bucket": 64})]
+    assert obs.snapshot()["histograms"][
+        "span_seconds{name=meta.demo}"]["count"] == 2
+
+
 # ---------------------------------------------------------------------------
 # training plane: Telemetry callback + gossip round metrics
 # ---------------------------------------------------------------------------
@@ -301,6 +349,52 @@ def test_telemetry_disabled_is_silent():
     assert res.history                           # the fit itself ran
     snap = obs.snapshot()
     assert not snap["counters"] and not snap["histograms"]
+
+
+def _span_counts(prefix="fit."):
+    return {k[len("span_seconds{name="):-1]: h["count"]
+            for k, h in obs.snapshot()["histograms"].items()
+            if k.startswith("span_seconds{name=" + prefix)}
+
+
+@pytest.mark.parametrize("rounds,every", [(10, 4), (8, 4), (5, 0)])
+def test_wave_round_spans(rounds, every):
+    """One ``fit.round`` and one ``fit.order`` per round, one ``fit.cost``
+    per eval boundary (every ``every`` rounds and the last)."""
+
+    from repro.mc import Trainer, Wave
+
+    problem = _small_problem()
+    obs.reset()
+    Trainer().fit(problem, Wave(num_rounds=rounds, eval_every=every), seed=0)
+    evals = math.ceil(rounds / every) if every else 1
+    assert _span_counts() == {"fit.wave": 1, "fit.round": rounds,
+                              "fit.order": rounds, "fit.cost": evals}
+
+
+def test_wave_round_spans_leave_the_fit_bit_identical():
+    from repro.mc import Trainer, Wave
+
+    problem = _small_problem()
+    sched = Wave(num_rounds=6, eval_every=2)
+    on = Trainer().fit(problem, sched, seed=3)
+    prev = obs.set_enabled(False)
+    try:
+        off = Trainer().fit(problem, sched, seed=3)
+    finally:
+        obs.set_enabled(prev)
+    for a, b in zip(on.state, off.state):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert on.history == off.history
+
+
+def test_full_gd_rounds_draw_no_order():
+    from repro.mc import FullGD, Trainer
+
+    problem = _small_problem()
+    obs.reset()
+    Trainer().fit(problem, FullGD(num_rounds=5, eval_every=5), seed=0)
+    assert _span_counts() == {"fit.full": 1, "fit.round": 5, "fit.cost": 1}
 
 
 # ---------------------------------------------------------------------------

@@ -360,3 +360,85 @@ def test_drain_blocks_until_empty():
         eng.drain()
         assert all(f.done() for f in futures)
         assert eng.metrics()["queue_depth"] == 0
+
+
+# --------------------------------------------------------------------------
+# spans on the worker path
+# --------------------------------------------------------------------------
+
+
+def _span_stats():
+    return {k[len("span_seconds{name="):-1]: h
+            for k, h in obs.snapshot()["histograms"].items()
+            if k.startswith("span_seconds{name=serve.")}
+
+
+def test_request_spans_cover_its_chunks():
+    """A request that splits into two chunks is one ``serve.request``
+    around two ``serve.dispatch`` and two ``serve.fetch`` spans, and each
+    chunk's batch time is its two spans' time."""
+
+    idx = _index()
+    with ServingEngine(idx, buckets=(8, 32, 64), k=K) as eng:
+        eng.recommend(np.arange(8))               # warm the 8 bucket
+        obs.reset()
+        eng.recommend(np.arange(100) % 120)       # 64 + 36 → two 64s
+        eng.drain()                               # the request span closed
+        spans = _span_stats()
+        assert {k: h["count"] for k, h in spans.items()} == {
+            "serve.request": 1, "serve.dispatch": 2, "serve.fetch": 2}
+        inner = spans["serve.dispatch"]["sum"] + spans["serve.fetch"]["sum"]
+        assert spans["serve.request"]["sum"] >= inner
+        batch = eng.metrics()["buckets"][64]
+        assert batch["count"] == 2
+        assert batch["sum"] == pytest.approx(inner)
+
+
+@pytest.mark.parametrize("size", [1, 9, 32, 64, 65, 129, 200])
+def test_bucket_counts_follow_the_ladder_plan(size):
+    """``metrics()["buckets"]`` counts one observation per planned chunk,
+    and one request span per request whatever its size."""
+
+    idx = _index()
+    buckets = (8, 32, 64)
+    with ServingEngine(idx, buckets=buckets, k=K) as eng:
+        obs.reset()
+        eng.recommend(np.arange(size) % 120)
+        eng.drain()
+        plan = eng.ladder.plan(size)
+        counts = {b: h["count"] for b, h in eng.metrics()["buckets"].items()}
+        assert counts == {b: sum(c[2] == b for c in plan) for b in buckets}
+        spans = _span_stats()
+        assert spans["serve.request"]["count"] == 1
+        assert spans["serve.dispatch"]["count"] == len(plan)
+        assert spans["serve.fetch"]["count"] == len(plan)
+
+
+def test_request_ids_tie_chunks_to_their_request(tmp_path):
+    """Under a profiler capture each request's spans share its ``rid``;
+    chunk spans also carry their ``bucket``."""
+
+    import glob
+
+    from jax.profiler import ProfileData
+
+    idx = _index()
+    with ServingEngine(idx, buckets=(8, 32), k=K) as eng:
+        eng.recommend(np.arange(40))              # compile outside
+        with obs.trace(str(tmp_path)):
+            eng.recommend_many([np.arange(5), np.arange(40)])
+            eng.drain()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    events = [(e.name, dict(e.stats))
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("serve.")]
+    reqs = sorted(st["rid"] for name, st in events if name == "serve.request")
+    assert len(reqs) == 2 and reqs[0] != reqs[1]
+    chunks = sorted((st["rid"], st["bucket"], name) for name, st in events
+                    if name != "serve.request")
+    assert chunks == [                            # 5 → 8; 40 → 32 + 8
+        (reqs[0], 8, "serve.dispatch"), (reqs[0], 8, "serve.fetch"),
+        (reqs[1], 8, "serve.dispatch"), (reqs[1], 8, "serve.fetch"),
+        (reqs[1], 32, "serve.dispatch"), (reqs[1], 32, "serve.fetch")]

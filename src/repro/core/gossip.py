@@ -60,7 +60,7 @@ from repro.core.state import Problem, State
 from repro.core import compress as C
 from repro.faults.plan import AGE_NEVER
 from repro.mesh.plan import MeshPlan
-from repro.sparse.store import SparseProblem
+from repro.sparse.store import SparseProblem, drop_tile
 
 
 class HaloState(NamedTuple):
@@ -479,15 +479,20 @@ def make_gossip_step(
     else:
         in_specs = (problem_spec, carry_spec)
         body_fn = shard_body
-    step = jax.jit(
-        jax.shard_map(
-            body_fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=carry_spec,
-            check_vma=False,
-        )
+    sharded = jax.shard_map(
+        body_fn,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=carry_spec,
+        check_vma=False,
     )
+    if layout == "sparse":
+        # a store's dense tile stays out of the mesh: gossip blocks take
+        # the segment path (the placed store's spec has no tile fields)
+        step = jax.jit(lambda problem, *rest: sharded(drop_tile(problem),
+                                                      *rest))
+    else:
+        step = jax.jit(sharded)
     return step, (problem_spec, carry_spec)
 
 
@@ -596,14 +601,15 @@ def _distributed_cost_fn(plan: MeshPlan, lam: float, sparse: bool):
         c = obj.total_cost(prob, U, W, lam)
         return jax.lax.psum(c, axes)
 
-    return jax.jit(
-        jax.shard_map(
-            local_cost, mesh=plan.mesh,
-            in_specs=(problem_spec, pspec2, pspec2),
-            out_specs=P(),
-            check_vma=False,
-        )
+    sharded = jax.shard_map(
+        local_cost, mesh=plan.mesh,
+        in_specs=(problem_spec, pspec2, pspec2),
+        out_specs=P(),
+        check_vma=False,
     )
+    if sparse:
+        return jax.jit(lambda prob, U, W: sharded(drop_tile(prob), U, W))
+    return jax.jit(sharded)
 
 
 def distributed_cost(mesh, problem: Problem | SparseProblem, state: State,

@@ -125,12 +125,12 @@ def structure_grads(
         lambda x, m, u, w: f_grads(x, m, u, w, use_kernel=use_kernel)
     )(x3, m3, u3, w3)
     del f
-    return _finish_structure_grads(
+    return finish_structure_grads(
         gu_f, gw_f, u3, w3, cf3, cu_pair, cw_pair, rho, lam
     )
 
 
-def _finish_structure_grads(gu_f, gw_f, u3, w3, cf3, cu_pair, cw_pair, rho, lam):
+def finish_structure_grads(gu_f, gw_f, u3, w3, cf3, cu_pair, cw_pair, rho, lam):
     """Shared tail of the structure gradient: λ-reg + Fig.-2 normalization +
     the two consensus pulls (identical for dense and sparse f-parts)."""
 
@@ -166,7 +166,7 @@ def structure_grads_sparse(
         )
     )(entries3, u3, w3)
     del f
-    return _finish_structure_grads(
+    return finish_structure_grads(
         gu_f, gw_f, u3, w3, cf3, cu_pair, cw_pair, rho, lam
     )
 

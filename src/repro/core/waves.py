@@ -66,7 +66,14 @@ def wave_step(
     bi, bj = idx[..., 0], idx[..., 1]                 # (S, 3)
     u3 = state.U[bi, bj]
     w3 = state.W[bi, bj]
-    if isinstance(problem, SparseProblem):            # layout="sparse"
+    if isinstance(problem, SparseProblem) and sparse_obj.takes_tile(
+            problem.entries, method, use_kernel):     # dense masked tiles
+        _, gu_f, gw_f = sparse_obj.tile_f_grads_at(problem.entries, bi, bj,
+                                                   u3, w3)
+        gu3, gw3 = jax.vmap(functools.partial(
+            obj.finish_structure_grads, rho=rho, lam=lam,
+        ))(gu_f, gw_f, u3, w3, tables.cf, tables.cu, tables.cw)
+    elif isinstance(problem, SparseProblem):          # layout="sparse"
         grad = jax.vmap(
             lambda entries, u, w, cf, cu, cw: obj.structure_grads_sparse(
                 entries, u, w, cf, cu, cw,

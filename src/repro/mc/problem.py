@@ -36,6 +36,7 @@ from repro.core.state import Problem, State, make_problem
 from repro.data.synthetic import MCDataset
 from repro.mesh.plan import MeshPlan
 from repro import sparse as sparse_mod
+from repro.sparse import objective as sparse_obj
 from repro.sparse.store import SparseProblem
 
 
@@ -280,6 +281,22 @@ class CompletionProblem:
     @property
     def layout(self) -> str:
         return "sparse" if isinstance(self.data, SparseProblem) else "dense"
+
+    @property
+    def grad_path(self) -> str:
+        """The arithmetic of the f-term on this problem's data: ``"dense"``
+        (the dense layout), ``"scatter"`` or ``"segment"`` (the sparse
+        engines), or ``"tile"`` (the store's dense masked tile, which the
+        default engine takes wherever the store built one)."""
+
+        if not isinstance(self.data, SparseProblem):
+            return "dense"
+        if self.engine.method == "scatter":
+            return "scatter"
+        if sparse_obj.takes_tile(self.data.entries, self.engine.method,
+                                 self.engine.use_kernel):
+            return "tile"
+        return "segment"
 
     @property
     def density(self) -> float:

@@ -52,6 +52,12 @@ class Schedule:
             eval_cb: EvalCb = None) -> tuple[State, list[tuple[int, float]]]:
         raise NotImplementedError
 
+    def grad_path(self, problem: CompletionProblem) -> str:
+        """The f-term arithmetic this schedule's rounds take on
+        ``problem`` (``CompletionProblem.grad_path``)."""
+
+        return problem.grad_path
+
 
 @dataclasses.dataclass(frozen=True)
 class Sequential(Schedule):
@@ -189,6 +195,11 @@ class Gossip(Schedule):
 
     name = "gossip"
     units = "rounds"
+
+    def grad_path(self, problem: CompletionProblem) -> str:
+        # the mesh step drops the store's dense tile (core/gossip.py)
+        path = problem.grad_path
+        return "segment" if path == "tile" else path
 
     def _plan(self, problem):
         from repro.mesh.plan import MeshPlan

@@ -228,6 +228,8 @@ class Trainer:
             )
         sched = make_schedule(schedule, **schedule_overrides)
         cfg = self._config_for(problem)
+        obs.counter("grad_engine_fits_total",
+                    path=sched.grad_path(problem)).inc()
         if key is None:
             key = jax.random.PRNGKey(seed)
 

@@ -397,9 +397,12 @@ class MeshPlan:
 
     def place_entries(self, sp):
         """Put a ``SparseProblem`` onto its owners: each device receives
-        exactly the blocks :meth:`local_blocks` assigns it."""
+        exactly the blocks :meth:`local_blocks` assigns it.  The dense
+        tile stays behind: placed stores keep the segment path."""
 
-        return self.place(sp, self.entries_spec())
+        from repro.sparse.store import drop_tile
+
+        return self.place(drop_tile(sp), self.entries_spec())
 
     def place_state(self, state):
         return self.place(state, self.state_spec())
@@ -410,11 +413,12 @@ def entries_spec_like(spec: P):
     one definition of the store's spec structure (``MeshPlan.entries_spec``
     and the back-compat ``SparseProblem.pspec`` both call this)."""
 
-    from repro.sparse.entries import BlockEntries
+    from repro.sparse.entries import SORTED_FIELDS, BlockEntries
     from repro.sparse.store import SparseProblem
 
+    # a placed store carries no dense tile (its fields stay None)
     return SparseProblem(
-        BlockEntries(*([spec] * len(BlockEntries._fields))), spec
+        BlockEntries(*([spec] * len(SORTED_FIELDS))), spec
     )
 
 

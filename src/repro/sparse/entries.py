@@ -18,12 +18,21 @@ Layout contract (see ``sparse/store.py`` for the full story):
     col_perm : (..., E) int32   — gather to column-sorted (CSC) order
     row_ptr  : (..., mb+1) int32 — CSR segment offsets over the entry axis
     col_ptr  : (..., nb+1) int32 — CSC segment offsets (in col_perm order)
+    tile_vals: (..., mb', nb') float32 — the block's values, dense (0 unseen)
+    tile_mask: (..., mb', nb') bool    — the block's observation mask, dense
+    (mb' ≥ mb, nb' ≥ nb: padded to whole device layout tiles, masked out)
 
 The three aux fields default to ``None`` (an empty pytree node, so vmap /
 tree_map / shard_map specs all compose): an unsorted COO bundle built with
 :meth:`from_coo` is a valid input for the order-agnostic ``scatter``
 gradient method, while the ``segment`` fast path requires
 :attr:`has_sorted_aux`.
+
+The two tile fields are a second arithmetic for the same f-term, present
+only where the store built them (``sparse/store.py`` ``tile_rule``: a
+block dense enough that three matrix products beat the segment engine's
+row gathers, and tiles that fit the device).  ``None`` otherwise —
+minibatches and mesh-placed stores never carry them.
 
 Leading batch axes are free: the store stacks blocks as (p, q, ...), the
 schedulers gather structure trios as (3, ...), and ``jax.vmap`` peels axes
@@ -46,6 +55,11 @@ from typing import NamedTuple, Optional
 
 import jax
 
+# the segment-sorted store's own fields: what every store carries, and
+# all that a mesh-placed store carries (its blocks take no tile)
+SORTED_FIELDS = ("rows", "cols", "vals", "valid", "col_perm", "row_ptr",
+                 "col_ptr")
+
 
 class BlockEntries(NamedTuple):
     """Padded-COO entries of one block (or a stack of blocks)."""
@@ -57,6 +71,8 @@ class BlockEntries(NamedTuple):
     col_perm: Optional[jax.Array] = None
     row_ptr: Optional[jax.Array] = None
     col_ptr: Optional[jax.Array] = None
+    tile_vals: Optional[jax.Array] = None
+    tile_mask: Optional[jax.Array] = None
 
     @property
     def capacity(self) -> int:
@@ -74,6 +90,18 @@ class BlockEntries(NamedTuple):
             and self.row_ptr is not None
             and self.col_ptr is not None
         )
+
+    @property
+    def has_tile(self) -> bool:
+        """True when the dense masked tile is attached: the f-term and its
+        gradients then come from matrix products, not segment reductions."""
+
+        return self.tile_vals is not None and self.tile_mask is not None
+
+    def without_tile(self) -> "BlockEntries":
+        """The same entries with the dense tile dropped (segment path)."""
+
+        return self._replace(tile_vals=None, tile_mask=None)
 
     @property
     def mb(self) -> int:

@@ -22,6 +22,15 @@ selected method; ``chunk`` tunes the segment-reduce chunk size (an engine
 option surfaced by ``repro.mc.EngineOptions`` and swept by
 ``benchmarks/sparse_vs_dense.py``).
 
+A block whose store carries the dense masked tile (``entries.has_tile``,
+built at ingest by ``sparse.store.tile_rule`` where the block is dense
+enough) computes the same f-term from it instead: ``R = M ⊙ (X − U Wᵀ)``,
+``‖R‖²``, ``−2 R W`` and ``−2 Rᵀ U`` as three matrix products at
+``Precision.HIGHEST``, all float32 — no row gathers, no segment reduction.
+The cost and the default (``"segment"``, no kernel) gradient take it;
+``method="scatter"`` and ``use_kernel=True`` name their engine explicitly
+and keep it.
+
 This module depends only on the sddmm kernel package so both
 ``core.objective`` and ``core.waves`` can import it without cycles.
 """
@@ -42,9 +51,86 @@ from repro.sparse.entries import BlockEntries
 from repro.sparse.store import SparseProblem
 
 
-def f_cost_sparse(entries: BlockEntries, u, w):
-    """‖valid ⊙ (vals − ⟨U[rows], W[cols]⟩)‖² for one block."""
+def _tile_residual(vals, mask, u, w):
+    """R = M ⊙ (X − U Wᵀ) on a block's dense tile, float32 throughout.
+    The tile may be larger than the block (padded to whole layout tiles,
+    masked out): the factors get zero rows to match."""
 
+    tm, tn = vals.shape
+    u = jnp.pad(u.astype(jnp.float32), ((0, tm - u.shape[0]), (0, 0)))
+    w = jnp.pad(w.astype(jnp.float32), ((0, tn - w.shape[0]), (0, 0)))
+    pred = jnp.matmul(u, w.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.where(mask, vals - pred, 0.0), u, w
+
+
+def _tile_terms(vals, mask, u, w):
+    """(f, gU, gW) from a block's dense values and mask: three matrix
+    products at ``HIGHEST`` (at the TPU's default precision they would
+    take their float32 operands as bfloat16)."""
+
+    r, up, wp = _tile_residual(vals, mask, u, w)
+    hi = jax.lax.Precision.HIGHEST
+    gu = -2.0 * jnp.matmul(r, wp, precision=hi)[:u.shape[0]]
+    gw = -2.0 * jnp.matmul(r.T, up, precision=hi)[:w.shape[0]]
+    return jnp.sum(r * r), gu.astype(u.dtype), gw.astype(w.dtype)
+
+
+def tile_f_grads(entries: BlockEntries, u, w):
+    """(f, gU, gW) for one block from its dense masked tile."""
+
+    return _tile_terms(entries.tile_vals, entries.tile_mask, u, w)
+
+
+def takes_tile(entries: BlockEntries, method: str = "segment",
+               use_kernel: bool = False) -> bool:
+    """Whether a block's f-gradients come from its dense tile: the store
+    carries one and the caller named no other engine (``"scatter"`` or
+    the Pallas kernel)."""
+
+    return entries.has_tile and method == "segment" and not use_kernel
+
+
+def tile_f_grads_at(entries: BlockEntries, bi, bj, u, w):
+    """(f, gU, gW) of blocks ``(bi, bj)`` of a tiled (p, q, ...) store.
+
+    ``bi``/``bj`` are int arrays of one static shape, ``u``/``w`` the
+    blocks' factors stacked the same way.  One block per loop step, its
+    tile a dynamic slice read inside its own products: a vmapped
+    ``tile[bi, bj]`` is a gather that XLA first copies into a stacked
+    (…, mb, nb) array, which made a wave 2.2× slower on a TPU v5 lite
+    (DESIGN.md §3); unrolled, the blocks read in place too, but the
+    program takes several times longer to compile."""
+
+    lead = bi.shape
+    bi, bj = bi.reshape(-1), bj.reshape(-1)
+    u = u.reshape((-1,) + u.shape[len(lead):])
+    w = w.reshape((-1,) + w.shape[len(lead):])
+    tm, tn = entries.tile_vals.shape[-2:]
+
+    def block(t, k):
+        return jax.lax.dynamic_slice(t, (bi[k], bj[k], 0, 0),
+                                     (1, 1, tm, tn))[0, 0]
+
+    def step(k, acc):
+        f, gu, gw = acc
+        fk, guk, gwk = _tile_terms(block(entries.tile_vals, k),
+                                   block(entries.tile_mask, k), u[k], w[k])
+        return f.at[k].set(fk), gu.at[k].set(guk), gw.at[k].set(gwk)
+
+    init = (jnp.zeros(bi.shape, jnp.float32), jnp.zeros_like(u),
+            jnp.zeros_like(w))
+    f, gu, gw = jax.lax.fori_loop(0, bi.shape[0], step, init)
+    return (f.reshape(lead), gu.reshape(lead + gu.shape[1:]),
+            gw.reshape(lead + gw.shape[1:]))
+
+
+def f_cost_sparse(entries: BlockEntries, u, w):
+    """‖valid ⊙ (vals − ⟨U[rows], W[cols]⟩)‖² for one block (from the
+    dense tile where the block carries one)."""
+
+    if entries.has_tile:
+        r, _, _ = _tile_residual(entries.tile_vals, entries.tile_mask, u, w)
+        return jnp.sum(r * r)
     e = sddmm_ref.sddmm_residuals(entries, u, w)
     return jnp.sum(e * e)
 
@@ -58,7 +144,9 @@ def f_grads_sparse(entries, u, w, *legacy, use_kernel: bool = False,
     segments; ``"scatter"`` is the order-agnostic scatter-add reference.
     ``use_kernel`` selects the Pallas implementation of the chosen method
     (the XLA paths double as fallbacks for VMEM-oversized blocks);
-    ``chunk`` tunes the XLA segment-reduce chunk size.
+    ``chunk`` tunes the XLA segment-reduce chunk size.  With neither
+    ``"scatter"`` nor ``use_kernel``, a block carrying its dense tile
+    (``entries.has_tile``) takes :func:`tile_f_grads` instead.
 
     The pre-BlockEntries positional shape
     ``(rows, cols, vals, valid, col_perm, row_ptr, col_ptr, u, w)`` is
@@ -87,6 +175,8 @@ def f_grads_sparse(entries, u, w, *legacy, use_kernel: bool = False,
         return sddmm_ref.sddmm_factor_grad_ref(entries, u, w)
     if method != "segment":
         raise ValueError(f"unknown method {method!r}; 'segment' or 'scatter'")
+    if takes_tile(entries, method, use_kernel):
+        return tile_f_grads(entries, u, w)
     # chunk=None -> the committed --chunks sweep's winner for this backend
     # (kernels/sddmm/autotune.py); an explicit chunk always wins
     chunk = sddmm_autotune.resolve_chunk(chunk)

@@ -41,6 +41,7 @@ from jax.sharding import PartitionSpec as P
 from repro import obs
 from repro.mesh.plan import MeshPlan
 from repro.sparse import store as store_mod
+from repro.sparse.entries import SORTED_FIELDS
 from repro.sparse.store import (
     DEFAULT_BUCKET,
     SparseProblem,
@@ -149,6 +150,7 @@ class ShardedEntries:
                 shards[sdi, sdj] = store_mod._pack_sorted(
                     blk[order], lrr[order], lcc[order], lvv[order],
                     bpr, bpc, mb, nb, bucket, headroom, capacity=E,
+                    tile=False,
                 )
         sp = cls._assemble(plan, shards, E, mb, nb)
         return cls(sp, plan), (mb * p, nb * q)
@@ -327,7 +329,7 @@ class ShardedEntries:
 
         entries = type(sp.entries)(*[
             rebuild(f, getattr(sp.entries, f), getattr(espec.entries, f))
-            for f in type(sp.entries)._fields
+            for f in SORTED_FIELDS
         ])
         nnz = rebuild("nnz", sp.nnz, espec.nnz)
         return ShardedEntries(SparseProblem(entries, nnz), plan)

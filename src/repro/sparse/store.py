@@ -13,6 +13,8 @@ keeps, per grid block, only the observed entries, bundled as a single
     entries.col_perm : (p, q, E)    int32   — permutation to col-sorted order
     entries.row_ptr  : (p, q, mb+1) int32   — CSR segment offsets
     entries.col_ptr  : (p, q, nb+1) int32   — CSC segment offsets
+    entries.tile_vals: (p, q, mb', nb') float32 — dense values (dense blocks)
+    entries.tile_mask: (p, q, mb', nb') bool    — dense mask   (dense blocks)
     nnz              : (p, q)       int32   — real entry count per block
 
 Entries are **segment-sorted** (DESIGN.md §3): real entries come first, in
@@ -24,6 +26,14 @@ view: gathering the entry axis through it yields column-sorted entries with
 stream stays non-decreasing end to end and gathers may legally advertise
 ``indices_are_sorted``), cols=0, vals=0, valid=0 and contribute nothing to
 any sum.
+
+A block dense enough also carries its values and mask as a dense tile
+(:func:`tile_rule`): three matrix products on the tile then cost less than
+the segment engine's per-entry row gathers, so ``sparse/objective.py``
+takes the tile wherever it is present.  The rule reads the block's
+capacity density, the backend and the device's memory; the tile is built
+once at ingest and kept in step by :func:`append_entries`; mb' × nb' is
+(mb, nb) padded to whole device layout tiles (:func:`tile_shape`).
 
 ``E`` is the per-block entry capacity: the maximum block nnz plus the
 requested *headroom* (pre-allocated append slack for streaming ingestion),
@@ -59,6 +69,33 @@ from repro.data.synthetic import MCDataset
 from repro.sparse.entries import BlockEntries
 
 DEFAULT_BUCKET = 256
+
+# Capacity density E / (mb·nb) at and above which a block's f-term costs
+# less from its dense masked tile than from the segment engine, per
+# backend.  cpu: DESIGN.md §3's table (2048², 4×4, rank 8) has the sorted
+# engine winning through 5 % (16.4 ms against dense 51 ms) and growing by
+# 2.6–4 ms a point of density, so the dense form overtakes it near 15 %.
+# tpu: one block visit timed on a TPU v5 lite at the ML-1M block
+# (1,510 × 927, rank 32; benchmarks/tile_crossover.py, DESIGN.md §3):
+# segment 894.8 / 364.2 / 284.2 / 257.5 µs at capacity 61,440 / 15,360 /
+# 3,840 / 960 against the tile's 60.4–63.4 µs.  The segment engine keeps a
+# floor (its per-row slab gathers do not shrink with the capacity), so the
+# tile won at every density measured; the constant is the lowest of them,
+# 960 / (1,510 · 927).  A backend with no measurement keeps the segment
+# path.
+TILE_CROSSOVER = {"cpu": 0.15, "tpu": 6.8e-4}
+# Share of the device's memory the whole grid's tiles may take: they stay
+# resident beside everything else a fit holds, and a wave step adds a
+# float32 residual as large as each tile it visits.
+TILE_MEMORY_SHARE = 1 / 8
+TILE_BYTES_PER_CELL = 4 + 1          # float32 value + bool mask
+# A tile's rows and columns are padded to whole (8, 128) tiles of the TPU's
+# memory layout, the padding masked out.  Unpadded (1,510 × 927 at ML-1M),
+# the device lays the (p, q, mb, nb) stack out with the grid axis q among
+# its minor dimensions (the layout with least padding), so one block is no
+# longer a contiguous slab and every read of a block became a strided copy:
+# over half the device time of a round in a trace on a TPU v5 lite.
+TILE_ALIGN = (8, 128)
 
 
 class SparseProblem(NamedTuple):
@@ -106,6 +143,12 @@ class SparseProblem(NamedTuple):
         return self.entries.capacity
 
     @property
+    def has_tile(self) -> bool:
+        """True when every block carries its dense masked tile."""
+
+        return self.entries.has_tile
+
+    @property
     def free_slots(self) -> jax.Array:
         """(p, q) append slack per block: capacity − nnz, i.e. how many
         entries :func:`append_entries` can still splice in before the
@@ -151,15 +194,95 @@ def bucketed_capacity(max_nnz: int, bucket: int = DEFAULT_BUCKET,
     return max(bucket, (max_nnz + headroom + bucket - 1) // bucket * bucket)
 
 
+def device_bytes_limit() -> int | None:
+    """The default device's memory in bytes, where the backend reports it
+    (the TPU does; the CPU backend reports nothing)."""
+
+    stats = jax.devices()[0].memory_stats()
+    return int(stats["bytes_limit"]) if stats and "bytes_limit" in stats \
+        else None
+
+
+def tile_shape(mb: int, nb: int) -> tuple[int, int]:
+    """A block's tile shape: (mb, nb) padded to whole :data:`TILE_ALIGN`
+    layout tiles."""
+
+    am, an = TILE_ALIGN
+    return -(-mb // am) * am, -(-nb // an) * an
+
+
+def tile_rule(capacity: int, mb: int, nb: int, blocks: int, backend: str,
+              bytes_limit: int | None) -> bool:
+    """Whether a store's blocks carry a dense masked tile.
+
+    Both must hold: the capacity density ``capacity / (mb·nb)`` is at or
+    above ``backend``'s crossover (the segment engine's cost grows with
+    the capacity, the tile's with mb·nb), and the ``blocks`` tiles fit
+    under :data:`TILE_MEMORY_SHARE` of ``bytes_limit`` where one is
+    known.  A backend without a measured crossover builds none."""
+
+    crossover = TILE_CROSSOVER.get(backend)
+    if crossover is None or mb * nb == 0:
+        return False
+    if capacity < crossover * mb * nb:
+        return False
+    tm, tn = tile_shape(mb, nb)
+    tile_bytes = blocks * tm * tn * TILE_BYTES_PER_CELL
+    return bytes_limit is None or tile_bytes <= TILE_MEMORY_SHARE * bytes_limit
+
+
+def _dense_tiles(blk, rr, cc, vv, p: int, q: int, mb: int, nb: int):
+    """(p, q, *tile_shape(mb, nb)) values and mask, on the device, from
+    block-routed COO entries (``blk`` the flat block index)."""
+
+    tm, tn = tile_shape(mb, nb)
+    vals = np.zeros((p * q, tm, tn), np.float32)
+    mask = np.zeros((p * q, tm, tn), bool)
+    vals[blk, rr, cc] = vv
+    mask[blk, rr, cc] = True
+    return (jnp.asarray(vals.reshape(p, q, tm, tn)),
+            jnp.asarray(mask.reshape(p, q, tm, tn)))
+
+
+def with_tile(sp: SparseProblem) -> SparseProblem:
+    """``sp`` with every block carrying its dense tile, whatever
+    :func:`tile_rule` would say: what tests and benchmarks use to compare
+    the two arithmetics on one store."""
+
+    if sp.has_tile:
+        return sp
+    p, q, _ = sp.rows.shape
+    nnz = np.asarray(sp.nnz).reshape(-1)
+    k = np.arange(sp.capacity)
+    real = k[None, :] < nnz[:, None]                 # (p·q, E)
+    blk = np.broadcast_to(np.arange(p * q)[:, None], real.shape)[real]
+    rows = np.asarray(sp.rows).reshape(p * q, -1)[real]
+    cols = np.asarray(sp.cols).reshape(p * q, -1)[real]
+    vals = np.asarray(sp.vals).reshape(p * q, -1)[real]
+    tv, tm = _dense_tiles(blk, rows, cols, vals, p, q, sp.mb, sp.nb)
+    return SparseProblem(sp.entries._replace(tile_vals=tv, tile_mask=tm),
+                         sp.nnz)
+
+
+def drop_tile(sp: SparseProblem) -> SparseProblem:
+    """The store without its dense tile: every f-term takes the segment
+    path (mesh placement, and tests that pin the segment engine)."""
+
+    return SparseProblem(sp.entries.without_tile(), sp.nnz)
+
+
 def _pack_sorted(blk, rr, cc, vv, p, q, mb, nb, bucket,
                  headroom: int = 0,
-                 capacity: int | None = None) -> SparseProblem:
+                 capacity: int | None = None,
+                 tile: bool = True) -> SparseProblem:
     """Shared packing tail: (block, row, col)-lexicographically sorted entry
     streams -> the padded, segment-sorted store.  ``blk`` must be
     non-decreasing with (rr, cc) lexicographic within each block.
     ``capacity`` forces the per-block capacity E — the sharded ingest path
     (``sparse/sharded.py``) packs each device's blocks independently but
-    must agree on one global E."""
+    must agree on one global E, and passes ``tile=False``: its stores keep
+    the segment path.  Otherwise :func:`tile_rule` decides whether the
+    blocks carry their dense tile."""
 
     total = len(blk)
     nnz = np.bincount(blk, minlength=p * q).astype(np.int64)
@@ -202,6 +325,11 @@ def _pack_sorted(blk, rr, cc, vv, p, q, mb, nb, bucket,
     col_ptr = np.zeros((p * q, nb + 1), np.int32)
     col_ptr[:, 1:] = np.cumsum(ccnt, axis=1)
 
+    tile_vals = tile_mask = None
+    if tile and tile_rule(E, mb, nb, p * q, jax.default_backend(),
+                          device_bytes_limit()):
+        tile_vals, tile_mask = _dense_tiles(blk, rr, cc, vv, p, q, mb, nb)
+
     entries = BlockEntries(
         jnp.asarray(rows.reshape(p, q, E)),
         jnp.asarray(cols.reshape(p, q, E)),
@@ -210,9 +338,11 @@ def _pack_sorted(blk, rr, cc, vv, p, q, mb, nb, bucket,
         jnp.asarray(col_perm.reshape(p, q, E)),
         jnp.asarray(row_ptr.reshape(p, q, mb + 1)),
         jnp.asarray(col_ptr.reshape(p, q, nb + 1)),
+        tile_vals, tile_mask,
     )
     sp = SparseProblem(entries, jnp.asarray(nnz.reshape(p, q).astype(np.int32)))
     obs.counter("ingest_entries_total").inc(total)
+    obs.gauge("ingest_tile_blocks").set(p * q if entries.has_tile else 0)
     # min over blocks: the append slack of the block that would raise first
     obs.gauge("ingest_free_slots").set(int(E - (nnz.max() if total else 0)))
     return sp
@@ -430,9 +560,10 @@ def append_entries(
     ``row_ptr``/``col_ptr`` gain the cumulated per-row/col insert counts
     and ``col_perm`` is re-threaded by the same merge in the (col, row)
     dual order — so the segment-reduce fast path stays valid without ever
-    re-sorting the stored prefix (DESIGN.md §11).  Capacity is untouched:
-    jitted consumers keep their compiled executables, which is the point
-    of pre-allocating ``headroom=`` at ingest.
+    re-sorting the stored prefix (DESIGN.md §11).  A dense tile, where
+    the store carries one, gets the new values and mask cells.  Capacity
+    is untouched: jitted consumers keep their compiled executables, which
+    is the point of pre-allocating ``headroom=`` at ingest.
 
     A (row, col) pair already present updates its value in place (an
     edited rating) and costs no slot; duplicate pairs within one append
@@ -483,6 +614,14 @@ def append_entries(
         _splice_block(ent, rptr, cptr, nnz, int(b), rr[sel], cc[sel],
                       vals[sel], mb, nb, E, label=f"({i},{j})")
 
+    tile_vals = tile_mask = None
+    if sp.has_tile:       # the dense tile follows the splice: set the cells
+        tile_vals = np.asarray(sp.entries.tile_vals).copy()
+        tile_mask = np.asarray(sp.entries.tile_mask).copy()
+        tile_vals[bi, bj, rr, cc] = vals
+        tile_mask[bi, bj, rr, cc] = True
+        tile_vals, tile_mask = jnp.asarray(tile_vals), jnp.asarray(tile_mask)
+
     entries = BlockEntries(
         jnp.asarray(ent["rows"].reshape(p, q, E)),
         jnp.asarray(ent["cols"].reshape(p, q, E)),
@@ -491,6 +630,7 @@ def append_entries(
         jnp.asarray(ent["col_perm"].reshape(p, q, E)),
         jnp.asarray(rptr.reshape(p, q, mb + 1)),
         jnp.asarray(cptr.reshape(p, q, nb + 1)),
+        tile_vals, tile_mask,
     )
     out = SparseProblem(entries,
                         jnp.asarray(nnz.reshape(p, q).astype(np.int32)))

@@ -199,11 +199,14 @@ try:
 except ValueError as e:
     assert "does not tile" in str(e)
 
-# owner-routed ingest == global from_entries, leaf for leaf
+# owner-routed ingest == global from_entries, leaf for leaf (the sorted
+# fields: a placed store carries no dense tile)
+from repro.sparse.entries import SORTED_FIELDS
 sp_ref, (M, N) = sparse.from_entries(rows, cols, vals, m, n, p, q, headroom=64)
 sh, (M2, N2) = ShardedEntries.from_coo(rows, cols, vals, m, n, plan, headroom=64)
 assert (M, N) == (M2, N2)
-for f in sp_ref.entries._fields:
+assert not sh.sp.has_tile
+for f in SORTED_FIELDS:
     np.testing.assert_array_equal(np.asarray(getattr(sh.sp.entries, f)),
                                   np.asarray(getattr(sp_ref.entries, f)))
 np.testing.assert_array_equal(np.asarray(sh.sp.nnz), np.asarray(sp_ref.nnz))
@@ -217,20 +220,22 @@ arows = rng.integers(0, m, 60); acols = rng.integers(0, n, 60)
 avals = rng.normal(size=60).astype(np.float32)
 ref2 = sparse.append_entries(sp_ref, arows, acols, avals)
 sh2 = sh.append(arows, acols, avals)
-for f in sp_ref.entries._fields:
+assert not sh2.sp.has_tile
+for f in SORTED_FIELDS:
     np.testing.assert_array_equal(np.asarray(getattr(sh2.sp.entries, f)),
                                   np.asarray(getattr(ref2.entries, f)))
 np.testing.assert_array_equal(np.asarray(sh2.sp.nnz), np.asarray(ref2.nnz))
 
-# shard-local f-gradients == global vmap.  The math is block-local, but
-# XLA vectorizes the segment sums differently for a device's 2x2 blocks
-# than for the global 4x4 batch, so the f32 sums round in another order:
-# allow 8 ulps of the largest gradient (about 4 ulps are observed)
+# shard-local f-gradients == global vmap of the same segment engine.  The
+# math is block-local, but XLA vectorizes the segment sums differently for
+# a device's 2x2 blocks than for the global 4x4 batch, so the f32 sums
+# round in another order: allow 8 ulps of the largest gradient (about 4
+# ulps are observed)
 U = jnp.asarray(rng.normal(size=(p, q, M // p, r)), jnp.float32)
 W = jnp.asarray(rng.normal(size=(p, q, N // q, r)), jnp.float32)
 gu, gw = f_grads_sharded(sh2, U, W)
 _, gu0, gw0 = jax.vmap(jax.vmap(lambda e, u, w: f_grads_sparse(e, u, w)))(
-    ref2.entries, U, W)
+    ref2.entries.without_tile(), U, W)
 eps = float(np.finfo(np.float32).eps)
 for g, g0 in ((gu, gu0), (gw, gw0)):
     tol = 8 * eps * float(jnp.max(jnp.abs(g0)))

@@ -1,6 +1,7 @@
 """Sparse block engine: store round-trips and sorted-layout invariants,
 sparse-vs-dense equivalence of objective/gradients (1e-5, segment and
-scatter methods), SDDMM kernel vs oracle, minibatch sampler."""
+scatter methods, with and without the dense masked tile), the rule that
+builds the tile, SDDMM kernel vs oracle, minibatch sampler."""
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,11 @@ from repro.core import sequential, waves
 from repro.core.state import build_tables, init_state, make_problem
 from repro.data import lowrank_problem
 from repro.kernels.sddmm import sddmm_factor_grad, sddmm_factor_grad_ref
-from repro import sparse
+from repro import obs, sparse
+from repro.sparse import objective as sparse_obj
+from repro.sparse import store as store_mod
+
+from _tile_paths import PATHS, on_path
 
 
 def _problem(m=96, n=80, p=3, q=2, r=4, density=0.2, seed=0):
@@ -167,26 +172,30 @@ def test_from_dataset_matches_dense_problem():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("pq,density,seed", [
     ((2, 2), 0.05, 0), ((3, 2), 0.2, 1), ((2, 4), 0.5, 2), ((4, 4), 0.1, 3),
 ])
-def test_objective_matches_dense(pq, density, seed):
+def test_objective_matches_dense(pq, density, seed, path):
     p, q = pq
     spec, cfg, prob, sp = _problem(m=16 * p, n=12 * q, p=p, q=q,
                                    density=density, seed=seed)
+    sp = on_path(sp, path)
     st = init_state(jax.random.PRNGKey(seed), spec)
     c_d = float(obj.total_cost(prob, st.U, st.W, cfg.lam))
     c_s = float(obj.total_cost(sp, st.U, st.W, cfg.lam))
     np.testing.assert_allclose(c_s, c_d, rtol=1e-5)
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("pq,density,seed", [
     ((2, 2), 0.05, 0), ((3, 2), 0.2, 1), ((2, 4), 0.5, 2), ((4, 4), 0.1, 3),
 ])
-def test_full_gradients_match_dense(pq, density, seed):
+def test_full_gradients_match_dense(pq, density, seed, path):
     p, q = pq
     spec, cfg, prob, sp = _problem(m=16 * p, n=12 * q, p=p, q=q,
                                    density=density, seed=seed)
+    sp = on_path(sp, path)
     st = init_state(jax.random.PRNGKey(seed + 10), spec)
     gU_d, gW_d = waves.full_gradients(prob, st.U, st.W, rho=cfg.rho, lam=cfg.lam)
     gU_s, gW_s = waves.full_gradients(sp, st.U, st.W, rho=cfg.rho, lam=cfg.lam)
@@ -198,14 +207,15 @@ def test_full_gradients_match_dense(pq, density, seed):
                                rtol=1e-5, atol=1e-5 * scale)
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("use_kernel", [False, True])
-def test_segment_and_scatter_methods_agree_with_dense(use_kernel):
+def test_segment_and_scatter_methods_agree_with_dense(use_kernel, path):
     """Sorted (segment), unsorted (scatter) and dense ∇L agree at 1e-5; the
-    Pallas implementations of both methods agree too (interpret on CPU)."""
-
-    from repro.sparse import objective as sparse_obj
+    Pallas implementations of both methods agree too (interpret on CPU),
+    whether or not the store carries its dense tile."""
 
     spec, cfg, prob, sp = _problem(m=48, n=36, p=3, q=2, density=0.15, seed=4)
+    sp = on_path(sp, path)
     st = init_state(jax.random.PRNGKey(21), spec)
     gd = waves.full_gradients(prob, st.U, st.W, rho=cfg.rho, lam=cfg.lam)
     for method in ("segment", "scatter"):
@@ -226,11 +236,10 @@ def test_segment_and_scatter_methods_agree_with_dense(use_kernel):
 def test_f_grads_sparse_legacy_positional_shape_warns():
     """The pre-BlockEntries 9-positional signature still works but warns."""
 
-    from repro.sparse import objective as sparse_obj
-
     spec, cfg, prob, sp = _problem(m=48, n=36, p=3, q=2, density=0.15, seed=4)
     st = init_state(jax.random.PRNGKey(21), spec)
-    want = sparse_obj.f_grads_sparse(sp.entries.gather(0, 0),
+    # the positional shape carries no dense tile: it takes the segment path
+    want = sparse_obj.f_grads_sparse(sp.entries.gather(0, 0).without_tile(),
                                      st.U[0, 0], st.W[0, 0])
     with pytest.warns(DeprecationWarning):
         got = sparse_obj.f_grads_sparse(
@@ -242,10 +251,12 @@ def test_f_grads_sparse_legacy_positional_shape_warns():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b))
 
 
-def test_sequential_step_matches_dense():
+@pytest.mark.parametrize("path", PATHS)
+def test_sequential_step_matches_dense(path):
     """Same PRNG key -> same sampled structure -> identical update."""
 
     spec, cfg, prob, sp = _problem()
+    sp = on_path(sp, path)
     st = init_state(jax.random.PRNGKey(2), spec)
     tables = build_tables(spec.p, spec.q, G.enumerate_structures(spec.p, spec.q))
     k = jax.random.PRNGKey(7)
@@ -258,16 +269,231 @@ def test_sequential_step_matches_dense():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_wave_fit_sparse_layout_matches_dense():
+@pytest.mark.parametrize("path", PATHS)
+def test_wave_fit_sparse_layout_matches_dense(path):
     spec, cfg, prob, sp = _problem()
     key = jax.random.PRNGKey(0)
     st_d, hist_d = waves._fit(prob, spec, cfg, key, num_rounds=3)
-    st_s, hist_s = waves._fit(prob, spec, cfg, key, num_rounds=3,
-                              layout="sparse")
+    st_s, hist_s = waves._fit(on_path(sparse.ensure_layout(prob, "sparse"),
+                                      path),
+                              spec, cfg, key, num_rounds=3, layout="sparse")
     np.testing.assert_allclose(np.asarray(st_s.U), np.asarray(st_d.U),
                                rtol=1e-5, atol=1e-5)
     assert hist_s[-1][0] == hist_d[-1][0]
     np.testing.assert_allclose(hist_s[-1][1], hist_d[-1][1], rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The dense masked tile: every f-term entry point, and the rule
+# ---------------------------------------------------------------------------
+
+
+def _padded_problem(r=3, seed=5):
+    """50 x 37 ratings on a 3 x 2 grid (padding rows and columns), with
+    block (2, 1) left without a single rating: an all-padding block."""
+
+    m, n, p, q = 50, 37, 3, 2
+    rng = np.random.default_rng(seed)
+    mask = rng.random((m, n)) < 0.3
+    mask[34:, 19:] = False                      # block (2, 1): mb 17, nb 19
+    rows, cols = np.nonzero(mask)
+    vals = rng.normal(size=len(rows)).astype(np.float32)
+    sp, (mp, n_p) = sparse.from_entries(rows, cols, vals, m, n, p, q,
+                                        bucket=64)
+    spec = G.GridSpec(mp, n_p, p, q, r)
+    x = np.zeros((m, n), np.float32)
+    x[rows, cols] = vals
+    xp, mpad, _, _ = G.pad_to_grid(x, mask.astype(np.float32), p, q)
+    prob = make_problem(xp, mpad, spec)
+    return spec, prob, sp
+
+
+def _close(a, b, rtol=1e-5):
+    a, b = np.asarray(a), np.asarray(b)
+    scale = float(np.max(np.abs(b))) + 1e-12
+    np.testing.assert_allclose(a, b, rtol=rtol, atol=rtol * scale)
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_f_term_entry_points_match_dense_with_grid_padding(path):
+    """f_grads_sparse, f_cost_sparse, structure_grads_sparse,
+    full_gradients_sparse and total_report_cost_sparse against the dense
+    Problem at 1e-5, on a padded grid with an all-padding block, with the
+    dense tile and without it."""
+
+    spec, prob, sp = _padded_problem()
+    sp = on_path(sp, path)
+    assert int(sp.nnz[2, 1]) == 0
+    st = init_state(jax.random.PRNGKey(3), spec)
+    for i in range(spec.p):
+        for j in range(spec.q):
+            ent = sp.entries.gather(i, j)
+            u, w = st.U[i, j], st.W[i, j]
+            want = obj.f_grads(prob.xb[i, j], prob.maskb[i, j], u, w)
+            got = sparse_obj.f_grads_sparse(ent, u, w)
+            for a, b in zip(got, want):
+                _close(a, b)
+            _close(sparse_obj.f_cost_sparse(ent, u, w),
+                   obj.f_cost(prob.xb[i, j], prob.maskb[i, j], u, w))
+    _, gu, gw = sparse_obj.f_grads_sparse(sp.entries.gather(2, 1),
+                                          st.U[2, 1], st.W[2, 1])
+    assert float(jnp.abs(gu).max()) == 0.0 == float(jnp.abs(gw).max())
+
+    tables = build_tables(spec.p, spec.q,
+                          G.enumerate_structures(spec.p, spec.q))
+    for s in range(tables.blocks.shape[0]):
+        bi, bj = tables.blocks[s, :, 0], tables.blocks[s, :, 1]
+        args = (st.U[bi, bj], st.W[bi, bj], tables.cf[s], tables.cu[s],
+                tables.cw[s])
+        want = obj.structure_grads(prob.xb[bi, bj], prob.maskb[bi, bj],
+                                   *args, rho=1.0, lam=0.1)
+        got = obj.structure_grads_sparse(sp.entries.gather(bi, bj), *args,
+                                         rho=1.0, lam=0.1)
+        for a, b in zip(got, want):
+            _close(a, b)
+
+    gd = waves.full_gradients(prob, st.U, st.W, rho=1.0, lam=0.1)
+    gs = sparse_obj.full_gradients_sparse(sp, st.U, st.W, rho=1.0, lam=0.1)
+    for a, b in zip(gs, gd):
+        _close(a, b)
+    _close(sparse_obj.total_report_cost_sparse(sp, st.U, st.W, 0.1),
+           obj.total_report_cost(prob.xb, prob.maskb, st.U, st.W, 0.1))
+
+
+def test_tile_and_segment_paths_agree_in_every_wave():
+    """One wave_step of every wave: the tile path (blocks sliced one by one
+    inside their products) against the segment path and the dense one."""
+
+    spec, cfg, prob, sp = _problem(m=64, n=48, p=4, q=4, density=0.3,
+                                   seed=6)
+    st = init_state(jax.random.PRNGKey(4), spec)
+    kw = dict(rho=cfg.rho, lam=cfg.lam, a=1e-2, b=cfg.b)
+    for tables in waves.wave_tables(spec.p, spec.q):
+        tile = waves.wave_step(on_path(sp, "tile"), st, tables, **kw)
+        seg = waves.wave_step(on_path(sp, "segment"), st, tables, **kw)
+        dense = waves.wave_step(prob, st, tables, **kw)
+        for a, b, c in zip(tile[:2], seg[:2], dense[:2]):
+            _close(a, c)
+            _close(b, c)
+        assert int(tile.t) == int(seg.t) == int(dense.t)
+
+
+def test_scatter_method_and_kernel_keep_their_engine():
+    """A store with a tile still honours an explicit engine: ``scatter``
+    and ``use_kernel`` never take the tile, the default does."""
+
+    spec, cfg, prob, sp = _problem(m=48, n=36, p=3, q=2, density=0.15, seed=4)
+    sp = on_path(sp, "tile")
+    ent = sp.entries
+    assert sparse_obj.takes_tile(ent)
+    assert not sparse_obj.takes_tile(ent, method="scatter")
+    assert not sparse_obj.takes_tile(ent, use_kernel=True)
+    assert not sparse_obj.takes_tile(ent.without_tile())
+
+
+def test_tile_rule_reads_density_backend_and_memory():
+    rule = store_mod.tile_rule
+    cpu = store_mod.TILE_CROSSOVER["cpu"]
+    mb, nb = 100, 80
+    above = int(np.ceil(cpu * mb * nb))
+    assert rule(above, mb, nb, 16, "cpu", None)
+    assert not rule(above - 1, mb, nb, 16, "cpu", None)
+    assert not rule(above, mb, nb, 16, "no-such-backend", None)
+    tiles = 16 * 104 * 128 * store_mod.TILE_BYTES_PER_CELL     # padded
+    share = store_mod.TILE_MEMORY_SHARE
+    assert rule(above, mb, nb, 16, "cpu", int(np.ceil(tiles / share)))
+    assert not rule(above, mb, nb, 16, "cpu", int(tiles / share) - 1)
+    tpu = store_mod.TILE_CROSSOVER["tpu"]
+    assert rule(61440, 1510, 927, 16, "tpu", 16 * 2**30)      # ML-1M, v5e
+    assert not rule(int(tpu * 1510 * 927) - 1, 1510, 927, 16, "tpu", None)
+
+
+def test_ingest_builds_tile_by_the_rule(monkeypatch):
+    """Above the crossover every block carries its tile and the gauge
+    counts them; below it, or over the memory share, none does."""
+
+    ds = lowrank_problem(48, 36, 3, density=0.2, seed=2)
+    rows, cols = np.nonzero(ds.train_mask)
+    vals = ds.x[rows, cols]
+
+    def ingest():
+        sp, _ = sparse.from_entries(rows, cols, vals, 48, 36, 3, 2,
+                                    bucket=32)
+        return sp, obs.gauge("ingest_tile_blocks").value
+
+    monkeypatch.setitem(store_mod.TILE_CROSSOVER, "cpu", 0.01)
+    sp, gauge = ingest()
+    assert sp.has_tile and gauge == 6
+    assert store_mod.tile_shape(16, 18) == (16, 128)  # whole layout tiles
+    assert sp.entries.tile_vals.shape == (3, 2, 16, 128)
+    assert sp.entries.tile_mask.dtype == jnp.bool_
+    xb, maskb = sparse.to_dense(sp)
+    tv = np.asarray(sp.entries.tile_vals)
+    tm = np.asarray(sp.entries.tile_mask)
+    np.testing.assert_array_equal(tv[..., :18], xb)
+    np.testing.assert_array_equal(tm[..., :18], maskb > 0)
+    assert not tm[..., 18:].any() and not tv[..., 18:].any()
+    np.testing.assert_array_equal(
+        np.asarray(sparse.with_tile(sparse.drop_tile(sp)).entries.tile_vals),
+        tv)
+
+    monkeypatch.setattr(store_mod, "device_bytes_limit", lambda: 1000)
+    sp, gauge = ingest()
+    assert not sp.has_tile and gauge == 0
+
+    monkeypatch.setattr(store_mod, "device_bytes_limit", lambda: None)
+    monkeypatch.setitem(store_mod.TILE_CROSSOVER, "cpu", 2.0)
+    sp, gauge = ingest()
+    assert not sp.has_tile and gauge == 0
+
+
+def test_minibatch_and_placed_stores_carry_no_tile():
+    from repro.mesh import MeshPlan, build_mesh
+    from repro.sparse.sharded import ShardedEntries
+
+    spec, cfg, prob, sp = _problem(m=48, n=36, p=3, q=2, density=0.3, seed=1)
+    sp = on_path(sp, "tile")
+    key = jax.random.PRNGKey(0)
+    assert not sparse.sample_minibatch(key, sp, 16).has_tile
+    assert not sparse.MinibatchStream(sp, 16, seed=0).batch_at(3).has_tile
+    plan = MeshPlan.build(3, 2, mesh=build_mesh((1, 1), ("data", "model")))
+    assert not plan.place_entries(sp).has_tile
+    assert not ShardedEntries.from_problem(sp, plan).sp.has_tile
+    ds = lowrank_problem(48, 36, 3, density=0.3, seed=1)
+    rows, cols = np.nonzero(ds.train_mask)
+    sharded, _ = ShardedEntries.from_coo(rows, cols, ds.x[rows, cols],
+                                         48, 36, plan)
+    assert not sharded.sp.has_tile
+    appended = sharded.append(rows[:3], (cols[:3] + 1) % 36,
+                              np.ones(3, np.float32))
+    assert not appended.sp.has_tile
+
+
+def test_grad_engine_counter_counts_the_path_each_fit_takes():
+    import dataclasses
+
+    from repro.mc import CompletionProblem, FullGD, Gossip, Trainer, Wave
+
+    ds = lowrank_problem(48, 36, 3, density=0.3, seed=1)
+    prob = CompletionProblem.from_dataset(ds, 3, 2, 3, layout="sparse")
+    tiled = dataclasses.replace(prob, data=on_path(prob.data, "tile"))
+    segment = dataclasses.replace(prob, data=on_path(prob.data, "segment"))
+    dense = prob.with_layout("dense")
+
+    def count(path):
+        return obs.counter("grad_engine_fits_total", path=path).value
+
+    cases = [(tiled, Wave(num_rounds=1), "tile"),
+             (tiled, FullGD(num_rounds=1), "tile"),
+             (segment, Wave(num_rounds=1), "segment"),
+             (tiled.with_engine(method="scatter"), Wave(num_rounds=1),
+              "scatter"),
+             (tiled, Gossip(num_rounds=1), "segment"),
+             (dense, Wave(num_rounds=1), "dense")]
+    for problem, sched, path in cases:
+        before = count(path)
+        Trainer().fit(problem, sched, seed=0)
+        assert count(path) == before + 1, (sched, path)
 
 
 def test_ensure_layout():

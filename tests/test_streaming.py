@@ -17,6 +17,7 @@ from repro.mc import (CompletionProblem, Incremental, Trainer, Wave,
 from repro import sparse
 
 from test_sparse import check_sorted_store_invariants
+from _tile_paths import PATHS, on_path
 
 
 def _coo_problem(m=60, n=48, p=3, q=2, density=0.2, seed=0, base_frac=0.7,
@@ -40,27 +41,41 @@ def _coo_problem(m=60, n=48, p=3, q=2, density=0.2, seed=0, base_frac=0.7,
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("path", PATHS)
 @pytest.mark.parametrize("seed,base_frac", [(0, 0.7), (1, 0.5), (2, 0.95)])
-def test_append_matches_fresh_ingest(seed, base_frac):
+def test_append_matches_fresh_ingest(seed, base_frac, path):
     """Base ingest + append == one-shot ingest of the union, entry for
     entry (to_dense), and the appended store satisfies every sorted-layout
-    invariant — the segment fast path never notices the splice."""
+    invariant — the segment fast path never notices the splice.  A dense
+    tile follows the splice cell for cell."""
 
     sp, (rr, cc, vv), (base, stream) = _coo_problem(seed=seed,
                                                     base_frac=base_frac)
+    sp = on_path(sp, path)
     out = sparse.append_entries(sp, rr[stream], cc[stream], vv[stream])
     check_sorted_store_invariants(out)
     assert out.capacity == sp.capacity                 # no shape change
+    assert out.has_tile == (path == "tile")
     ref, _ = sparse.from_entries(rr, cc, vv, 60, 48, 3, 2, bucket=32)
     xa, ma = sparse.to_dense(out)
     xb, mb = sparse.to_dense(ref)
     np.testing.assert_array_equal(ma, mb)
     np.testing.assert_array_equal(xa, xb)
+    if path == "tile":
+        fresh = np.asarray(sparse.with_tile(ref).entries.tile_vals)
+        np.testing.assert_array_equal(np.asarray(out.entries.tile_vals),
+                                      fresh)
+        tm = np.asarray(out.entries.tile_mask)
+        np.testing.assert_array_equal(tm[..., :mb.shape[-2], :mb.shape[-1]],
+                                      mb > 0)
+        assert tm.sum() == (mb > 0).sum()
 
 
-def test_append_keeps_segment_gradients_exact():
+@pytest.mark.parametrize("path", PATHS)
+def test_append_keeps_segment_gradients_exact(path):
     """Gradients on an appended store match the dense oracle at 1e-5 — the
-    incrementally patched CSR/CSC views feed the segment engine correctly."""
+    incrementally patched CSR/CSC views feed the segment engine correctly,
+    and the patched dense tile gives a fresh ingest's gradients exactly."""
 
     m, n, p, q, r = 48, 36, 3, 2, 4
     rng = np.random.default_rng(3)
@@ -72,8 +87,10 @@ def test_append_keeps_segment_gradients_exact():
     sp, _ = sparse.from_entries(rr[perm[:cut]], cc[perm[:cut]],
                                 x[rr, cc][perm[:cut]], m, n, p, q,
                                 bucket=32, headroom=128)
+    sp = on_path(sp, path)
     out = sparse.append_entries(sp, rr[perm[cut:]], cc[perm[cut:]],
                                 x[rr, cc][perm[cut:]])
+    assert out.has_tile == (path == "tile")
     spec = G.GridSpec(m, n, p, q, r)
     prob = make_problem(x, mask, spec)
     st = init_state(jax.random.PRNGKey(0), spec)
@@ -83,6 +100,13 @@ def test_append_keeps_segment_gradients_exact():
         scale = float(jnp.max(jnp.abs(b))) + 1e-12
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5 * scale)
+    if path == "tile":
+        fresh, _ = sparse.from_entries(rr, cc, x[rr, cc], m, n, p, q,
+                                       bucket=32, headroom=128)
+        gf = waves.full_gradients(on_path(fresh, "tile"), st.U, st.W,
+                                  rho=0.1, lam=0.01)
+        for a, b in zip(gs, gf):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_append_empty_is_noop():
@@ -90,12 +114,14 @@ def test_append_empty_is_noop():
     assert sparse.append_entries(sp, [], [], []) is sp
 
 
-def test_append_duplicate_updates_value_in_place():
+@pytest.mark.parametrize("path", PATHS)
+def test_append_duplicate_updates_value_in_place(path):
     """An existing (row, col) pair costs no slot: nnz is unchanged and the
-    stored value is replaced; within-batch duplicates resolve to the last
-    occurrence."""
+    stored value is replaced, in the dense tile too; within-batch
+    duplicates resolve to the last occurrence."""
 
     sp, (rr, cc, vv), (base, _) = _coo_problem()
+    sp = on_path(sp, path)
     r0, c0 = int(rr[base[0]]), int(cc[base[0]])
     out = sparse.append_entries(sp, [r0, r0], [c0, c0],
                                 np.array([5.0, 9.0], np.float32))
@@ -104,6 +130,9 @@ def test_append_duplicate_updates_value_in_place():
     xa, _ = sparse.to_dense(out)
     mb, nb = sp.mb, sp.nb
     assert xa[r0 // mb, c0 // nb, r0 % mb, c0 % nb] == 9.0
+    if path == "tile":
+        tv = np.asarray(out.entries.tile_vals)
+        assert tv[r0 // mb, c0 // nb, r0 % mb, c0 % nb] == 9.0
 
 
 def test_append_overflow_raises_with_headroom_hint():

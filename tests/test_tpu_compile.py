@@ -31,7 +31,8 @@ from repro.kernels.sddmm.ops import _MAX_VMEM_BYTES, segment_vmem_bytes
 from repro.kernels.sddmm.segment_kernel import sddmm_segment_grad_pallas
 from repro.mesh import MeshPlan
 from repro.sparse.entries import BlockEntries
-from repro.sparse.store import DEFAULT_BUCKET, SparseProblem, bucketed_capacity
+from repro.sparse.store import (DEFAULT_BUCKET, SparseProblem,
+                                bucketed_capacity, tile_shape)
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM_BYTES = 16 * 2**30          # one v5e chip
@@ -92,11 +93,15 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _store(p, q, mb, nb, E, sharding):
-    """SparseProblem of shapes: every leaf stacked over the (p, q) grid."""
+def _store(p, q, mb, nb, E, sharding, tile=False):
+    """SparseProblem of shapes: every leaf stacked over the (p, q) grid,
+    with the dense masked tile when ``tile``."""
 
     i32, f32 = jnp.int32, jnp.float32
     grid = (p, q)
+    shape = grid + tile_shape(mb, nb)
+    tiles = ((_sds(shape, f32, sharding), _sds(shape, jnp.bool_, sharding))
+             if tile else (None, None))
     return SparseProblem(
         BlockEntries(
             rows=_sds(grid + (E,), i32, sharding),
@@ -106,6 +111,8 @@ def _store(p, q, mb, nb, E, sharding):
             col_perm=_sds(grid + (E,), i32, sharding),
             row_ptr=_sds(grid + (mb + 1,), i32, sharding),
             col_ptr=_sds(grid + (nb + 1,), i32, sharding),
+            tile_vals=tiles[0],
+            tile_mask=tiles[1],
         ),
         _sds(grid, i32, sharding),
     )
@@ -186,6 +193,27 @@ def test_wave_step_fits_one_chip_at_ml1m(one_chip, ml1m):
         rho=1e2, lam=1e-6, a=1e-3, b=5e-7,
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_wave_step_with_tile_fits_one_chip_at_ml1m(one_chip, ml1m):
+    """The smoke's training step on dense masked tiles (the path the
+    ML-1M blocks take on the chip) compiles and fits one chip's HBM, and
+    reads each block's tile inside its products: no block is copied out
+    first, so its temporaries stay under one block's tile."""
+
+    size, block, headroom = ml1m
+    g = size.grid
+    mb, nb, E = block(g, headroom)
+    tables = _wave_tables(g, g, one_chip)
+    compiled = waves.wave_step.lower(
+        _store(g, g, mb, nb, E, one_chip, tile=True),
+        _state(g, g, mb, nb, size.rank, one_chip),
+        tables,
+        rho=1e2, lam=1e-6, a=1e-3, b=5e-7,
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    one_tile = mb * nb * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_tile
 
 
 def test_wave_step_with_kernel_compiles_at_kernel_grid(one_chip, ml1m,

@@ -587,6 +587,16 @@ def init_carry(state: State, round0: int = 0) -> GossipCarry:
     )
 
 
+@functools.lru_cache(maxsize=64)
+def gossip_step(spec_pq: tuple[int, int], cfg: GossipMCConfig, **kw):
+    """:func:`make_gossip_step` (``mesh=None``, so pass ``plan=``), built
+    once per argument set: a fit that resumes or repeats another's rounds
+    reuses its compiled step instead of tracing and compiling it again.
+    A ``FaultPlan`` is keyed by identity."""
+
+    return make_gossip_step(None, spec_pq, cfg, **kw)
+
+
 @functools.lru_cache(maxsize=None)
 def _distributed_cost_fn(plan: MeshPlan, lam: float, sparse: bool):
     """Jitted Σ-cost for one (plan, λ, layout) — cached so eval

@@ -66,23 +66,15 @@ def wave_step(
     bi, bj = idx[..., 0], idx[..., 1]                 # (S, 3)
     u3 = state.U[bi, bj]
     w3 = state.W[bi, bj]
-    if isinstance(problem, SparseProblem) and sparse_obj.takes_tile(
-            problem.entries, method, use_kernel):     # dense masked tiles
-        _, gu_f, gw_f = sparse_obj.tile_f_grads_at(problem.entries, bi, bj,
-                                                   u3, w3)
+    if isinstance(problem, SparseProblem):            # layout="sparse"
+        # one block per loop step: a dense masked tile where the store
+        # carries one, else the block's entries (sparse/objective.py)
+        _, gu_f, gw_f = sparse_obj.f_grads_at(
+            problem.entries, bi, bj, u3, w3,
+            use_kernel=use_kernel, method=method, chunk=chunk)
         gu3, gw3 = jax.vmap(functools.partial(
             obj.finish_structure_grads, rho=rho, lam=lam,
         ))(gu_f, gw_f, u3, w3, tables.cf, tables.cu, tables.cw)
-    elif isinstance(problem, SparseProblem):          # layout="sparse"
-        grad = jax.vmap(
-            lambda entries, u, w, cf, cu, cw: obj.structure_grads_sparse(
-                entries, u, w, cf, cu, cw,
-                rho=rho, lam=lam, use_kernel=use_kernel, method=method,
-                chunk=chunk,
-            )
-        )
-        gu3, gw3 = grad(problem.entries.gather(bi, bj),
-                        u3, w3, tables.cf, tables.cu, tables.cw)
     else:
         grad = jax.vmap(
             lambda x, m, u, w, cf, cu, cw: obj.structure_grads(

@@ -58,7 +58,7 @@ DIRECTIONS = ("left_u", "right_u", "up_w", "down_w")
 AGE_NEVER = 1_000_000
 
 
-@dataclasses.dataclass(frozen=True)
+@dataclasses.dataclass(frozen=True, eq=False)
 class FaultPlan:
     """Seed-keyed fault schedule for the gossip plane.
 
@@ -67,7 +67,10 @@ class FaultPlan:
     round.  ``restart`` tags the recovery generation: :meth:`refold`
     bumps it, so a self-healed fit draws a fresh (but still
     deterministic) fault stream instead of replaying the one that
-    killed it."""
+    killed it.
+
+    Plans compare and hash by identity (``key`` may be an array), so a
+    plan can key the cache of compiled gossip steps."""
 
     key: Any = 0
     p_drop_edge: float = 0.0

@@ -23,10 +23,18 @@ import os
 
 from repro.kernels.sddmm.segment import SEG_CHUNK
 
-# sane defaults when no committed sweep covers the backend: CPU measured
-# fastest at the original SEG_CHUNK scale; accelerators amortize the
-# chunk-prefix cumsum over wider lanes
-FALLBACK_CHUNK = {"cpu": SEG_CHUNK, "gpu": 64, "tpu": 128}
+# defaults when no committed sweep covers the backend.  tpu: one block
+# visit on a TPU v5 lite (benchmarks/segment_stages.py, DESIGN.md §3),
+# two runs of best-of-5 at chunks 64 / 128 / 256: 18.37, 18.22 / 18.87,
+# 19.04 / 18.37, 18.34 ms at the ML-10M block (17,892 x 2,671, rank 64,
+# 573,440 slots), 1.86 / 1.66 / 1.83 ms at the ML-1M block (1,510 x 927,
+# rank 32, 61,440 slots).  64 and 256 are within the runs' spread at
+# ML-10M and 128 is 3 % slower there; 256, at which the ML-10M cell was
+# measured, is kept, since the ML-10M block is where the segment engine
+# runs (ML-1M blocks carry a dense tile on the TPU, sparse/store.py
+# tile_rule).  cpu keeps the original SEG_CHUNK
+# scale; gpu is unmeasured.
+FALLBACK_CHUNK = {"cpu": SEG_CHUNK, "gpu": 64, "tpu": 256}
 
 # repo-relative location of the committed sweep (absent in installed
 # trees — the fallback table then applies)

@@ -278,8 +278,8 @@ class Gossip(Schedule):
 
         def step_for(n: int):
             if n not in steps:
-                steps[n], _ = core_gossip.make_gossip_step(
-                    None, (problem.spec.p, problem.spec.q), cfg, plan=plan,
+                steps[n], _ = core_gossip.gossip_step(
+                    (problem.spec.p, problem.spec.q), cfg, plan=plan,
                     staleness=self.staleness, compression=self.compression,
                     topk_fraction=self.topk_fraction,
                     use_kernel=eng.use_kernel, steps_per_call=n,

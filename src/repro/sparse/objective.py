@@ -90,31 +90,31 @@ def takes_tile(entries: BlockEntries, method: str = "segment",
     return entries.has_tile and method == "segment" and not use_kernel
 
 
-def tile_f_grads_at(entries: BlockEntries, bi, bj, u, w):
-    """(f, gU, gW) of blocks ``(bi, bj)`` of a tiled (p, q, ...) store.
+def _block(t, i, j):
+    """Block ``(i, j)`` of a (p, q, ...) leaf, as a dynamic slice."""
+
+    return jax.lax.dynamic_slice(t, (i, j) + (0,) * (t.ndim - 2),
+                                 (1, 1) + t.shape[2:])[0, 0]
+
+
+def _blockwise(visit, bi, bj, u, w):
+    """(f, gU, gW) of blocks ``(bi, bj)``, one block per loop step.
 
     ``bi``/``bj`` are int arrays of one static shape, ``u``/``w`` the
-    blocks' factors stacked the same way.  One block per loop step, its
-    tile a dynamic slice read inside its own products: a vmapped
-    ``tile[bi, bj]`` is a gather that XLA first copies into a stacked
-    (…, mb, nb) array, which made a wave 2.2× slower on a TPU v5 lite
-    (DESIGN.md §3); unrolled, the blocks read in place too, but the
-    program takes several times longer to compile."""
+    blocks' factors stacked the same way; ``visit(bi, bj, u, w, k)`` gives
+    the k-th block's terms from the flattened stacks.  Only one block's
+    temporaries are live at a time, and each block's data is a dynamic
+    slice read in place: a vmapped ``leaf[bi, bj]`` is a gather that XLA
+    first copies into a stacked array."""
 
     lead = bi.shape
     bi, bj = bi.reshape(-1), bj.reshape(-1)
     u = u.reshape((-1,) + u.shape[len(lead):])
     w = w.reshape((-1,) + w.shape[len(lead):])
-    tm, tn = entries.tile_vals.shape[-2:]
-
-    def block(t, k):
-        return jax.lax.dynamic_slice(t, (bi[k], bj[k], 0, 0),
-                                     (1, 1, tm, tn))[0, 0]
 
     def step(k, acc):
         f, gu, gw = acc
-        fk, guk, gwk = _tile_terms(block(entries.tile_vals, k),
-                                   block(entries.tile_mask, k), u[k], w[k])
+        fk, guk, gwk = visit(bi, bj, u, w, k)
         return f.at[k].set(fk), gu.at[k].set(guk), gw.at[k].set(gwk)
 
     init = (jnp.zeros(bi.shape, jnp.float32), jnp.zeros_like(u),
@@ -122,6 +122,46 @@ def tile_f_grads_at(entries: BlockEntries, bi, bj, u, w):
     f, gu, gw = jax.lax.fori_loop(0, bi.shape[0], step, init)
     return (f.reshape(lead), gu.reshape(lead + gu.shape[1:]),
             gw.reshape(lead + gw.shape[1:]))
+
+
+def tile_f_grads_at(entries: BlockEntries, bi, bj, u, w):
+    """(f, gU, gW) of blocks ``(bi, bj)`` of a tiled (p, q, ...) store.
+
+    One block per loop step (:func:`_blockwise`), its tile a dynamic slice
+    read inside its own products: a vmapped ``tile[bi, bj]`` made a wave
+    2.2× slower on a TPU v5 lite (DESIGN.md §3); unrolled, the blocks read
+    in place too, but the program takes several times longer to compile."""
+
+    def visit(bi, bj, u, w, k):
+        return _tile_terms(_block(entries.tile_vals, bi[k], bj[k]),
+                           _block(entries.tile_mask, bi[k], bj[k]),
+                           u[k], w[k])
+
+    return _blockwise(visit, bi, bj, u, w)
+
+
+def f_grads_at(entries: BlockEntries, bi, bj, u, w, *,
+               use_kernel: bool = False, method: str = "segment",
+               chunk: int | None = None):
+    """(f, gU, gW) of blocks ``(bi, bj)`` of a (p, q, ...) store, one
+    block per loop step, shaped like ``bi``.
+
+    A tiled store (with the default engine) takes :func:`tile_f_grads_at`;
+    otherwise each block's entries are sliced out and go through
+    :func:`f_grads_sparse`, so only one block's per-entry (E, r)
+    temporaries are live at a time — vmapped over a wave's twelve
+    ML-10M blocks they took gigabytes (DESIGN.md §3)."""
+
+    if takes_tile(entries, method, use_kernel):
+        return tile_f_grads_at(entries, bi, bj, u, w)
+    entries = entries.without_tile()
+
+    def visit(bi, bj, u, w, k):
+        block = jax.tree.map(lambda t: _block(t, bi[k], bj[k]), entries)
+        return f_grads_sparse(block, u[k], w[k], use_kernel=use_kernel,
+                              method=method, chunk=chunk)
+
+    return _blockwise(visit, bi, bj, u, w)
 
 
 def f_cost_sparse(entries: BlockEntries, u, w):
@@ -185,16 +225,32 @@ def f_grads_sparse(entries, u, w, *legacy, use_kernel: bool = False,
     return sddmm_seg.sddmm_segment_grad_ref(entries, u, w, chunk=chunk)
 
 
+def _block_cost(entries: BlockEntries, u, w, *, lam: float):
+    return (f_cost_sparse(entries, u, w)
+            + lam * jnp.sum(u * u) + lam * jnp.sum(w * w))
+
+
+@partial(jax.jit, static_argnames=("lam",))
+def _blockwise_cost(entries: BlockEntries, U, W, *, lam: float):
+    """The cost of a tile-less store, one block per loop step, so only
+    one block's (E, r) row gathers are live.  Jitted: traced once per
+    shape, where an op-by-op ``lax.map`` would trace and compile its
+    body at every call."""
+
+    flat = jax.tree.map(lambda t: t.reshape((-1,) + t.shape[2:]),
+                        (entries, U, W))
+    return jnp.sum(jax.lax.map(lambda a: _block_cost(*a, lam=lam), flat))
+
+
 def total_report_cost_sparse(sp: SparseProblem, U, W, lam: float):
-    """Paper Table-2 cost Σ f_ij + λ‖U_ij‖² + λ‖W_ij‖², nnz-proportional."""
+    """Paper Table-2 cost Σ f_ij + λ‖U_ij‖² + λ‖W_ij‖², nnz-proportional.
 
-    def per_block(entries, u, w):
-        return (
-            f_cost_sparse(entries, u, w)
-            + lam * jnp.sum(u * u) + lam * jnp.sum(w * w)
-        )
+    Tiled blocks are vmapped; tile-less blocks are visited one per loop
+    step (:func:`_blockwise_cost`)."""
 
-    per = jax.vmap(jax.vmap(per_block))(sp.entries, U, W)
+    if not sp.has_tile:
+        return _blockwise_cost(sp.entries, U, W, lam=lam)
+    per = jax.vmap(jax.vmap(partial(_block_cost, lam=lam)))(sp.entries, U, W)
     return jnp.sum(per)
 
 

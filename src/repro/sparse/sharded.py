@@ -153,6 +153,8 @@ class ShardedEntries:
                     tile=False,
                 )
         sp = cls._assemble(plan, shards, E, mb, nb)
+        # each shard's pack set the gauge to its own share: the whole grid's
+        store_mod.set_padding_share(len(rows), p * q, E)
         return cls(sp, plan), (mb * p, nb * q)
 
     @classmethod

@@ -232,7 +232,7 @@ def tile_rule(capacity: int, mb: int, nb: int, blocks: int, backend: str,
 
 
 def _dense_tiles(blk, rr, cc, vv, p: int, q: int, mb: int, nb: int):
-    """(p, q, *tile_shape(mb, nb)) values and mask, on the device, from
+    """(p, q, *tile_shape(mb, nb)) values and mask, on the host, from
     block-routed COO entries (``blk`` the flat block index)."""
 
     tm, tn = tile_shape(mb, nb)
@@ -240,8 +240,16 @@ def _dense_tiles(blk, rr, cc, vv, p: int, q: int, mb: int, nb: int):
     mask = np.zeros((p * q, tm, tn), bool)
     vals[blk, rr, cc] = vv
     mask[blk, rr, cc] = True
-    return (jnp.asarray(vals.reshape(p, q, tm, tn)),
-            jnp.asarray(mask.reshape(p, q, tm, tn)))
+    return vals.reshape(p, q, tm, tn), mask.reshape(p, q, tm, tn)
+
+
+def set_padding_share(nnz_total: int, blocks: int, capacity: int) -> None:
+    """The ``ingest_padding_share`` gauge: the share of the store's slots
+    that hold no rating, 1 − Σ nnz ÷ (blocks × capacity).  The segment
+    engine pays for every slot; ``bench/workcount.py`` counts ratings."""
+
+    obs.gauge("ingest_padding_share").set(
+        1.0 - nnz_total / (blocks * capacity) if blocks * capacity else 0.0)
 
 
 def with_tile(sp: SparseProblem) -> SparseProblem:
@@ -260,7 +268,8 @@ def with_tile(sp: SparseProblem) -> SparseProblem:
     cols = np.asarray(sp.cols).reshape(p * q, -1)[real]
     vals = np.asarray(sp.vals).reshape(p * q, -1)[real]
     tv, tm = _dense_tiles(blk, rows, cols, vals, p, q, sp.mb, sp.nb)
-    return SparseProblem(sp.entries._replace(tile_vals=tv, tile_mask=tm),
+    return SparseProblem(sp.entries._replace(tile_vals=jnp.asarray(tv),
+                                             tile_mask=jnp.asarray(tm)),
                          sp.nnz)
 
 
@@ -283,6 +292,28 @@ def _pack_sorted(blk, rr, cc, vv, p, q, mb, nb, bucket,
     must agree on one global E, and passes ``tile=False``: its stores keep
     the segment path.  Otherwise :func:`tile_rule` decides whether the
     blocks carry their dense tile."""
+
+    with obs.span("ingest.sort", annotate=True):
+        host = _sorted_host(blk, rr, cc, vv, p, q, mb, nb, bucket, headroom,
+                            capacity, tile)
+    with obs.span("ingest.upload", annotate=True):
+        entries = BlockEntries(*(None if a is None else jnp.asarray(a)
+                                 for a in host[:-1]))
+        sp = SparseProblem(entries, jnp.asarray(host[-1]))
+    total, E = len(blk), entries.capacity
+    nnz = host[-1]
+    obs.counter("ingest_entries_total").inc(total)
+    obs.gauge("ingest_tile_blocks").set(p * q if entries.has_tile else 0)
+    # min over blocks: the append slack of the block that would raise first
+    obs.gauge("ingest_free_slots").set(int(E - (nnz.max() if total else 0)))
+    set_padding_share(total, p * q, E)
+    return sp
+
+
+def _sorted_host(blk, rr, cc, vv, p, q, mb, nb, bucket, headroom, capacity,
+                 tile):
+    """The host arrays of :func:`_pack_sorted`'s store, in
+    ``BlockEntries`` field order, then the (p, q) nnz counts."""
 
     total = len(blk)
     nnz = np.bincount(blk, minlength=p * q).astype(np.int64)
@@ -325,27 +356,15 @@ def _pack_sorted(blk, rr, cc, vv, p, q, mb, nb, bucket,
     col_ptr = np.zeros((p * q, nb + 1), np.int32)
     col_ptr[:, 1:] = np.cumsum(ccnt, axis=1)
 
-    tile_vals = tile_mask = None
+    tiles = (None, None)
     if tile and tile_rule(E, mb, nb, p * q, jax.default_backend(),
                           device_bytes_limit()):
-        tile_vals, tile_mask = _dense_tiles(blk, rr, cc, vv, p, q, mb, nb)
-
-    entries = BlockEntries(
-        jnp.asarray(rows.reshape(p, q, E)),
-        jnp.asarray(cols.reshape(p, q, E)),
-        jnp.asarray(vals.reshape(p, q, E)),
-        jnp.asarray(valid.reshape(p, q, E)),
-        jnp.asarray(col_perm.reshape(p, q, E)),
-        jnp.asarray(row_ptr.reshape(p, q, mb + 1)),
-        jnp.asarray(col_ptr.reshape(p, q, nb + 1)),
-        tile_vals, tile_mask,
-    )
-    sp = SparseProblem(entries, jnp.asarray(nnz.reshape(p, q).astype(np.int32)))
-    obs.counter("ingest_entries_total").inc(total)
-    obs.gauge("ingest_tile_blocks").set(p * q if entries.has_tile else 0)
-    # min over blocks: the append slack of the block that would raise first
-    obs.gauge("ingest_free_slots").set(int(E - (nnz.max() if total else 0)))
-    return sp
+        tiles = _dense_tiles(blk, rr, cc, vv, p, q, mb, nb)
+    return (rows.reshape(p, q, E), cols.reshape(p, q, E),
+            vals.reshape(p, q, E), valid.reshape(p, q, E),
+            col_perm.reshape(p, q, E), row_ptr.reshape(p, q, mb + 1),
+            col_ptr.reshape(p, q, nb + 1), *tiles,
+            nnz.reshape(p, q).astype(np.int32))
 
 
 def from_blocks(
@@ -410,7 +429,8 @@ def from_entries(
     bi, rr = rows // mb, rows % mb
     bj, cc = cols // nb, cols % nb
     blk = bi * q + bj
-    order = np.lexsort((cc, rr, blk))              # (block, row, col) lexicographic
+    with obs.span("ingest.sort", annotate=True):
+        order = np.lexsort((cc, rr, blk))          # (block, row, col) lexicographic
     sp = _pack_sorted(blk[order], rr[order].astype(np.int64),
                       cc[order].astype(np.int64), vals[order],
                       p, q, mb, nb, bucket, headroom)

@@ -429,6 +429,33 @@ def test_ingest_counters_track_store_and_appends():
     assert int(jnp.sum(sp2.nnz)) == len(rr)
 
 
+@pytest.mark.parametrize("tile", [True, False])
+def test_ingest_spans_and_padding_share(tile, monkeypatch):
+    """``from_entries`` records the host sort and the upload as
+    ``ingest.*`` spans and sets ``ingest_padding_share`` to 1 - sum of
+    nnz / (blocks x capacity), with the dense tile built or not."""
+
+    from repro.mc import CompletionProblem
+    from repro.sparse import store
+
+    monkeypatch.setitem(store.TILE_CROSSOVER, "cpu", 0.0 if tile else 2.0)
+    rng = np.random.default_rng(3)
+    lin = rng.choice(40 * 30, 250, replace=False)
+    obs.reset()
+    problem = CompletionProblem.from_entries(
+        lin // 30, lin % 30, rng.normal(size=250), (40, 30), 2, 3, 4)
+    sp = problem.data
+    assert sp.has_tile == tile
+    snap = obs.snapshot()
+    hists = snap["histograms"]
+    assert hists["span_seconds{name=ingest.sort}"]["count"] >= 1
+    assert hists["span_seconds{name=ingest.upload}"]["count"] == 1
+    share = snap["gauges"]["ingest_padding_share"]
+    assert share == pytest.approx(1 - 250 / (6 * sp.capacity))
+    assert share == pytest.approx(
+        1 - float(jnp.sum(sp.valid)) / sp.valid.size)
+
+
 # ---------------------------------------------------------------------------
 # serving plane
 # ---------------------------------------------------------------------------

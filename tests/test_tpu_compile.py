@@ -25,14 +25,16 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.config import GossipMCConfig
 from repro.core import gossip, waves
+from repro.core import objective as obj
 from repro.core.state import State
 from repro.kernels.quant.kernel import dequant_score_pallas
+from repro.kernels.sddmm.autotune import resolve_chunk
 from repro.kernels.sddmm.ops import _MAX_VMEM_BYTES, segment_vmem_bytes
 from repro.kernels.sddmm.segment_kernel import sddmm_segment_grad_pallas
 from repro.mesh import MeshPlan
 from repro.sparse.entries import BlockEntries
 from repro.sparse.store import (DEFAULT_BUCKET, SparseProblem,
-                                bucketed_capacity, tile_shape)
+                                bucketed_capacity, tile_rule, tile_shape)
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM_BYTES = 16 * 2**30          # one v5e chip
@@ -216,10 +218,43 @@ def test_wave_step_with_tile_fits_one_chip_at_ml1m(one_chip, ml1m):
     assert compiled.memory_analysis().temp_size_in_bytes < one_tile
 
 
+# the ML-10M deployment of bench/configs/ml10m-r64.json: 71,567 x 10,681
+# on a 4x4 grid, rank 64, 573,440 slots a block
+ML10M = dict(g=4, mb=17892, nb=2671, r=64, E=573440)
+
+
+def test_wave_step_and_cost_fit_one_chip_at_ml10m(one_chip):
+    """The ML-10M training step (tile-less blocks, the chip's segment
+    chunk) and the chunk cost compile for the chip and fit it.  Each
+    visits one block per loop step, so its temporaries are one block's
+    per-entry (E, r) float32 arrays: the row gathers, the contributions
+    and their chunk prefixes, at most eight alive at once (1.17 GB).  A
+    wave vmapped over its twelve blocks needs 1.76 GB for a single such
+    array, and the slab gather of the engine this one replaced took
+    13.1 GB alone."""
+
+    g, mb, nb, r, E = (ML10M[k] for k in ("g", "mb", "nb", "r", "E"))
+    assert not tile_rule(E, mb, nb, g * g, "tpu", HBM_BYTES)
+    bound = 8 * E * r * 4
+    store = _store(g, g, mb, nb, E, one_chip)
+    state = _state(g, g, mb, nb, r, one_chip)
+    step = waves.wave_step.lower(
+        store, state, _wave_tables(g, g, one_chip),
+        rho=1e2, lam=1e-6, a=1e-3, b=5e-7,
+        chunk=resolve_chunk(None, backend="tpu"),
+    ).compile()
+    cost = jax.jit(lambda sp, u, w: obj.total_cost(sp, u, w, 1e-6)).lower(
+        store, state.U, state.W).compile()
+    for compiled in (step, cost):
+        assert _device_bytes(compiled) < HBM_BYTES
+        assert compiled.memory_analysis().temp_size_in_bytes < bound
+
+
 def test_wave_step_with_kernel_compiles_at_kernel_grid(one_chip, ml1m,
                                                       monkeypatch):
-    """The smoke's ``use_kernel=True`` fit: the vmapped segment kernel
-    inside the wave step compiles to Mosaic on the kernel grid."""
+    """The smoke's ``use_kernel=True`` fit: the segment kernel inside the
+    wave step, one block per loop step, compiles to Mosaic on the kernel
+    grid."""
 
     # the kernel wrappers pick interpret mode off-TPU from the default
     # backend, which is the CPU here: steer them to the chip's branch

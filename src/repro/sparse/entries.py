@@ -18,9 +18,14 @@ Layout contract (see ``sparse/store.py`` for the full story):
     col_perm : (..., E) int32   — gather to column-sorted (CSC) order
     row_ptr  : (..., mb+1) int32 — CSR segment offsets over the entry axis
     col_ptr  : (..., nb+1) int32 — CSC segment offsets (in col_perm order)
-    tile_vals: (..., mb', nb') float32 — the block's values, dense (0 unseen)
-    tile_mask: (..., mb', nb') bool    — the block's observation mask, dense
-    (mb' ≥ mb, nb' ≥ nb: padded to whole device layout tiles, masked out)
+    tile_codes: (..., mb', nb') uint8  — the block's values as exact 1-byte
+                                         codes, 0 unseen
+    tile_grid : (..., 2) float32       — (lo, step): value = lo + (code−1)·step
+    tile_vals : (..., mb', nb') float32 — the block's values, dense (0 unseen)
+    tile_mask : (..., mb', nb') bool    — the block's observation mask, dense
+    (mb' ≥ mb, nb' ≥ nb: padded to whole device layout tiles, masked out;
+    a store has one grid, repeated per block so that every field leads
+    with the block axes and a block's slice carries its own)
 
 The three aux fields default to ``None`` (an empty pytree node, so vmap /
 tree_map / shard_map specs all compose): an unsorted COO bundle built with
@@ -28,11 +33,15 @@ tree_map / shard_map specs all compose): an unsorted COO bundle built with
 gradient method, while the ``segment`` fast path requires
 :attr:`has_sorted_aux`.
 
-The two tile fields are a second arithmetic for the same f-term, present
+The tile fields are a second arithmetic for the same f-term, present
 only where the store built them (``sparse/store.py`` ``tile_rule``: a
 block dense enough that three matrix products beat the segment engine's
-row gathers, and tiles that fit the device).  ``None`` otherwise —
-minibatches and mesh-placed stores never carry them.
+row gathers, and tiles that fit the device).  A store carries one of two
+encodings of the same tile: ``tile_codes``/``tile_grid`` where every
+value sits on an affine grid of at most 255 levels and decodes to itself
+bit for bit (rating scales do), else ``tile_vals``/``tile_mask``.  The
+other pair, and all four where there is no tile, are ``None`` —
+minibatches and mesh-placed stores never carry a tile.
 
 Leading batch axes are free: the store stacks blocks as (p, q, ...), the
 schedulers gather structure trios as (3, ...), and ``jax.vmap`` peels axes
@@ -59,6 +68,8 @@ import jax
 # all that a mesh-placed store carries (its blocks take no tile)
 SORTED_FIELDS = ("rows", "cols", "vals", "valid", "col_perm", "row_ptr",
                  "col_ptr")
+# the dense tile's fields, in either encoding
+TILE_FIELDS = ("tile_vals", "tile_mask", "tile_codes", "tile_grid")
 
 
 class BlockEntries(NamedTuple):
@@ -73,6 +84,8 @@ class BlockEntries(NamedTuple):
     col_ptr: Optional[jax.Array] = None
     tile_vals: Optional[jax.Array] = None
     tile_mask: Optional[jax.Array] = None
+    tile_codes: Optional[jax.Array] = None
+    tile_grid: Optional[jax.Array] = None
 
     @property
     def capacity(self) -> int:
@@ -92,16 +105,26 @@ class BlockEntries(NamedTuple):
         )
 
     @property
-    def has_tile(self) -> bool:
-        """True when the dense masked tile is attached: the f-term and its
-        gradients then come from matrix products, not segment reductions."""
+    def tile_encoding(self) -> Optional[str]:
+        """``"codes"`` or ``"f32"``: the encoding of the dense tile the
+        entries carry, ``None`` without one."""
 
-        return self.tile_vals is not None and self.tile_mask is not None
+        if self.tile_codes is not None:
+            return "codes"
+        return "f32" if self.tile_vals is not None else None
+
+    @property
+    def has_tile(self) -> bool:
+        """True when the dense masked tile is attached, in either
+        encoding: the f-term and its gradients then come from matrix
+        products, not segment reductions."""
+
+        return self.tile_encoding is not None
 
     def without_tile(self) -> "BlockEntries":
         """The same entries with the dense tile dropped (segment path)."""
 
-        return self._replace(tile_vals=None, tile_mask=None)
+        return self._replace(**dict.fromkeys(TILE_FIELDS))
 
     @property
     def mb(self) -> int:

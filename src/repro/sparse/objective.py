@@ -27,6 +27,9 @@ built at ingest by ``sparse.store.tile_rule`` where the block is dense
 enough) computes the same f-term from it instead: ``R = M ⊙ (X − U Wᵀ)``,
 ``‖R‖²``, ``−2 R W`` and ``−2 Rᵀ U`` as three matrix products at
 ``Precision.HIGHEST``, all float32 — no row gathers, no segment reduction.
+A tile stored as 1-byte codes is decoded inside the residual, ``M = code
+≠ 0`` and ``X = lo + (code − 1)·step``, to the stored values bit for bit:
+the arithmetic is the float32 tile's, only the bytes read are fewer.
 The cost and the default (``"segment"``, no kernel) gradient take it;
 ``method="scatter"`` and ``use_kernel=True`` name their engine explicitly
 and keep it.
@@ -48,7 +51,18 @@ from repro.kernels.sddmm import ops as sddmm_ops
 from repro.kernels.sddmm import ref as sddmm_ref
 from repro.kernels.sddmm import segment as sddmm_seg
 from repro.sparse.entries import BlockEntries
-from repro.sparse.store import SparseProblem
+from repro.sparse.store import SparseProblem, decode
+
+
+def _tile_cells(entries: BlockEntries, i=None, j=None):
+    """(values, mask) of a block's dense tile, float32 and bool: decoded
+    from its 1-byte codes (``sparse.store.decode``), or as stored.  With ``(i, j)`` the block is that of a (p, q, ...) store,
+    each field a dynamic slice read in place by the op that decodes it."""
+
+    get = (lambda t: t) if i is None else (lambda t: _block(t, i, j))
+    if entries.tile_encoding == "codes":
+        return decode(get(entries.tile_codes), get(entries.tile_grid))
+    return get(entries.tile_vals), get(entries.tile_mask)
 
 
 def _tile_residual(vals, mask, u, w):
@@ -72,13 +86,21 @@ def _tile_terms(vals, mask, u, w):
     hi = jax.lax.Precision.HIGHEST
     gu = -2.0 * jnp.matmul(r, wp, precision=hi)[:u.shape[0]]
     gw = -2.0 * jnp.matmul(r.T, up, precision=hi)[:w.shape[0]]
-    return jnp.sum(r * r), gu.astype(u.dtype), gw.astype(w.dtype)
+    return _sum_sq(r, u, w), gu.astype(u.dtype), gw.astype(w.dtype)
+
+
+def _sum_sq(r, u, w):
+    """‖R‖² over the block's own cells: the same sum, in the same order,
+    whatever layout padding the tile's encoding adds."""
+
+    r = r[:u.shape[0], :w.shape[0]]
+    return jnp.sum(r * r)
 
 
 def tile_f_grads(entries: BlockEntries, u, w):
     """(f, gU, gW) for one block from its dense masked tile."""
 
-    return _tile_terms(entries.tile_vals, entries.tile_mask, u, w)
+    return _tile_terms(*_tile_cells(entries), u, w)
 
 
 def takes_tile(entries: BlockEntries, method: str = "segment",
@@ -133,9 +155,7 @@ def tile_f_grads_at(entries: BlockEntries, bi, bj, u, w):
     in place too, but the program takes several times longer to compile."""
 
     def visit(bi, bj, u, w, k):
-        return _tile_terms(_block(entries.tile_vals, bi[k], bj[k]),
-                           _block(entries.tile_mask, bi[k], bj[k]),
-                           u[k], w[k])
+        return _tile_terms(*_tile_cells(entries, bi[k], bj[k]), u[k], w[k])
 
     return _blockwise(visit, bi, bj, u, w)
 
@@ -169,8 +189,8 @@ def f_cost_sparse(entries: BlockEntries, u, w):
     dense tile where the block carries one)."""
 
     if entries.has_tile:
-        r, _, _ = _tile_residual(entries.tile_vals, entries.tile_mask, u, w)
-        return jnp.sum(r * r)
+        r, _, _ = _tile_residual(*_tile_cells(entries), u, w)
+        return _sum_sq(r, u, w)
     e = sddmm_ref.sddmm_residuals(entries, u, w)
     return jnp.sum(e * e)
 
@@ -232,26 +252,32 @@ def _block_cost(entries: BlockEntries, u, w, *, lam: float):
 
 @partial(jax.jit, static_argnames=("lam",))
 def _blockwise_cost(entries: BlockEntries, U, W, *, lam: float):
-    """The cost of a tile-less store, one block per loop step, so only
-    one block's (E, r) row gathers are live.  Jitted: traced once per
-    shape, where an op-by-op ``lax.map`` would trace and compile its
-    body at every call."""
+    """The cost of a store, one block per loop step, so only one block's
+    temporaries are live: its (E, r) row gathers, or its tile's residual
+    and product (vmapped over sixteen ML-10M tiles, 16 × 192 MB each).
+    Each block is a dynamic slice read in place: a ``lax.map`` over the
+    stack reshaped to (p·q, ...) made XLA copy all the ML-10M codes to a
+    transposed layout first (compiled for a v5e).  Jitted: traced once
+    per shape, where an op-by-op loop would trace and compile its body
+    at every call."""
 
-    flat = jax.tree.map(lambda t: t.reshape((-1,) + t.shape[2:]),
-                        (entries, U, W))
-    return jnp.sum(jax.lax.map(lambda a: _block_cost(*a, lam=lam), flat))
+    p, q = U.shape[:2]
+
+    def step(k, per):
+        i, j = k // q, k % q
+        block = jax.tree.map(lambda t: _block(t, i, j), (entries, U, W))
+        return per.at[k].set(_block_cost(*block, lam=lam))
+
+    return jnp.sum(jax.lax.fori_loop(0, p * q, step,
+                                     jnp.zeros(p * q, jnp.float32)))
 
 
 def total_report_cost_sparse(sp: SparseProblem, U, W, lam: float):
-    """Paper Table-2 cost Σ f_ij + λ‖U_ij‖² + λ‖W_ij‖², nnz-proportional.
+    """Paper Table-2 cost Σ f_ij + λ‖U_ij‖² + λ‖W_ij‖², nnz-proportional,
+    one block per loop step whether or not the blocks carry a tile
+    (:func:`_blockwise_cost`)."""
 
-    Tiled blocks are vmapped; tile-less blocks are visited one per loop
-    step (:func:`_blockwise_cost`)."""
-
-    if not sp.has_tile:
-        return _blockwise_cost(sp.entries, U, W, lam=lam)
-    per = jax.vmap(jax.vmap(partial(_block_cost, lam=lam)))(sp.entries, U, W)
-    return jnp.sum(per)
+    return _blockwise_cost(sp.entries, U, W, lam=lam)
 
 
 def consensus_pulls(A: jax.Array, axis: int) -> jax.Array:

@@ -13,8 +13,10 @@ keeps, per grid block, only the observed entries, bundled as a single
     entries.col_perm : (p, q, E)    int32   — permutation to col-sorted order
     entries.row_ptr  : (p, q, mb+1) int32   — CSR segment offsets
     entries.col_ptr  : (p, q, nb+1) int32   — CSC segment offsets
-    entries.tile_vals: (p, q, mb', nb') float32 — dense values (dense blocks)
-    entries.tile_mask: (p, q, mb', nb') bool    — dense mask   (dense blocks)
+    entries.tile_codes: (p, q, mb', nb') uint8 — dense 1-byte value codes
+    entries.tile_grid : (p, q, 2)     float32 — their grid (lo, step)
+    entries.tile_vals : (p, q, mb', nb') float32 — or dense values
+    entries.tile_mask : (p, q, mb', nb') bool    — and their mask
     nnz              : (p, q)       int32   — real entry count per block
 
 Entries are **segment-sorted** (DESIGN.md §3): real entries come first, in
@@ -30,10 +32,15 @@ any sum.
 A block dense enough also carries its values and mask as a dense tile
 (:func:`tile_rule`): three matrix products on the tile then cost less than
 the segment engine's per-entry row gathers, so ``sparse/objective.py``
-takes the tile wherever it is present.  The rule reads the block's
-capacity density, the backend and the device's memory; the tile is built
-once at ingest and kept in step by :func:`append_entries`; mb' × nb' is
-(mb, nb) padded to whole device layout tiles (:func:`tile_shape`).
+takes the tile wherever it is present.  The tile is one exact 1-byte code
+a cell, ``value = lo + (code − 1)·step`` and 0 unobserved, where every
+stored value decodes to itself bit for bit on one grid of at most 255
+levels (:func:`code_grid`: any rating scale, minus a float32 mean); else a
+float32 value and a bool mask a cell.  No value is rounded onto a grid.
+The rule reads the block's capacity density, the backend, the device's
+memory and the encoding's bytes a cell; the tile is built once at ingest
+and kept in step by :func:`append_entries`; mb' × nb' is (mb, nb) padded
+to whole device layout tiles of the encoding's dtype (:func:`tile_shape`).
 
 ``E`` is the per-block entry capacity: the maximum block nnz plus the
 requested *headroom* (pre-allocated append slack for streaming ingestion),
@@ -66,7 +73,7 @@ import numpy as np
 from repro import obs
 from repro.core import grid as G
 from repro.data.synthetic import MCDataset
-from repro.sparse.entries import BlockEntries
+from repro.sparse.entries import TILE_FIELDS, BlockEntries
 
 DEFAULT_BUCKET = 256
 
@@ -88,14 +95,20 @@ TILE_CROSSOVER = {"cpu": 0.15, "tpu": 6.8e-4}
 # resident beside everything else a fit holds, and a wave step adds a
 # float32 residual as large as each tile it visits.
 TILE_MEMORY_SHARE = 1 / 8
-TILE_BYTES_PER_CELL = 4 + 1          # float32 value + bool mask
-# A tile's rows and columns are padded to whole (8, 128) tiles of the TPU's
-# memory layout, the padding masked out.  Unpadded (1,510 × 927 at ML-1M),
-# the device lays the (p, q, mb, nb) stack out with the grid axis q among
-# its minor dimensions (the layout with least padding), so one block is no
-# longer a contiguous slab and every read of a block became a strided copy:
-# over half the device time of a round in a trace on a TPU v5 lite.
-TILE_ALIGN = (8, 128)
+# Bytes a tile cell takes, per encoding: a 1-byte value code (0 unobserved)
+# where the store's values fit a grid (:func:`code_grid`), else a float32
+# value and a bool mask.
+TILE_BYTES_PER_CELL = {"codes": 1, "f32": 4 + 1}
+# A tile's rows and columns are padded to whole tiles of the TPU's memory
+# layout for its dtype, (32, 128) for 8 bits and (8, 128) for 32, the
+# padding masked out.  Unpadded (1,510 × 927 at ML-1M), the device lays
+# the (p, q, mb, nb) stack out with the grid axis q among its minor
+# dimensions (the layout with least padding), so one block is no longer a
+# contiguous slab and every read of a block became a strided copy: over
+# half the device time of a round in a trace on a TPU v5 lite.
+TILE_ALIGN = {"codes": (32, 128), "f32": (8, 128)}
+# Codes 1..255 name grid levels; 0 is an unobserved cell.
+CODE_LEVELS = 255
 
 
 class SparseProblem(NamedTuple):
@@ -203,44 +216,153 @@ def device_bytes_limit() -> int | None:
         else None
 
 
-def tile_shape(mb: int, nb: int) -> tuple[int, int]:
-    """A block's tile shape: (mb, nb) padded to whole :data:`TILE_ALIGN`
-    layout tiles."""
+def tile_shape(mb: int, nb: int, encoding: str) -> tuple[int, int]:
+    """A block's tile shape in ``encoding``: (mb, nb) padded to whole
+    layout tiles of its dtype (:data:`TILE_ALIGN`)."""
 
-    am, an = TILE_ALIGN
+    am, an = TILE_ALIGN[encoding]
     return -(-mb // am) * am, -(-nb // an) * an
 
 
 def tile_rule(capacity: int, mb: int, nb: int, blocks: int, backend: str,
-              bytes_limit: int | None) -> bool:
-    """Whether a store's blocks carry a dense masked tile.
+              bytes_limit: int | None, encoding: str) -> bool:
+    """Whether a store's blocks carry a dense masked tile in ``encoding``.
 
     Both must hold: the capacity density ``capacity / (mb·nb)`` is at or
     above ``backend``'s crossover (the segment engine's cost grows with
-    the capacity, the tile's with mb·nb), and the ``blocks`` tiles fit
-    under :data:`TILE_MEMORY_SHARE` of ``bytes_limit`` where one is
-    known.  A backend without a measured crossover builds none."""
+    the capacity, the tile's with mb·nb), and the ``blocks`` tiles, at
+    the encoding's :data:`TILE_BYTES_PER_CELL`, fit under
+    :data:`TILE_MEMORY_SHARE` of ``bytes_limit`` where one is known.  A
+    backend without a measured crossover builds none."""
 
     crossover = TILE_CROSSOVER.get(backend)
     if crossover is None or mb * nb == 0:
         return False
     if capacity < crossover * mb * nb:
         return False
-    tm, tn = tile_shape(mb, nb)
-    tile_bytes = blocks * tm * tn * TILE_BYTES_PER_CELL
+    tm, tn = tile_shape(mb, nb, encoding)
+    tile_bytes = blocks * tm * tn * TILE_BYTES_PER_CELL[encoding]
     return bytes_limit is None or tile_bytes <= TILE_MEMORY_SHARE * bytes_limit
 
 
-def _dense_tiles(blk, rr, cc, vv, p: int, q: int, mb: int, nb: int):
-    """(p, q, *tile_shape(mb, nb)) values and mask, on the host, from
-    block-routed COO entries (``blk`` the flat block index)."""
+def encode(vals, lo, step) -> tuple[np.ndarray, np.ndarray]:
+    """``vals``' 1-byte codes on the grid ``lo + (code − 1)·step``, and
+    for each whether it decodes to itself bit for bit in float32.  The
+    product ``(code − 1)·step`` must be exact too, so that a decode gives
+    the same bits whether or not the device fuses the multiply and the
+    add.  A value that fails gets code 1; callers keep no such code."""
 
-    tm, tn = tile_shape(mb, nb)
+    v = np.asarray(vals, np.float32)
+    lo, step = np.float32(lo), np.float32(step)
+    with np.errstate(invalid="ignore", over="ignore"):
+        k = np.rint((v.astype(np.float64) - lo) / step)
+        ok = (k >= 0) & (k < CODE_LEVELS)
+        k = np.where(ok, k, 0).astype(np.float32)
+        prod = k * step
+        ok &= prod.astype(np.float64) == k.astype(np.float64) * np.float64(
+            step)
+        ok &= (lo + prod).view(np.uint32) == v.view(np.uint32)
+    return (k + 1).astype(np.uint8), ok
+
+
+def decode(codes, grid):
+    """float32 values and bool mask of a tile's 1-byte ``codes`` on
+    ``grid`` (its last axis ``(lo, step)``): ``lo + (code − 1)·step`` and
+    ``code ≠ 0``, inverse of :func:`encode` bit for bit.  Traced inside
+    the residual that reads them (``sparse/objective.py``)."""
+
+    lo, step = grid[..., 0, None, None], grid[..., 1, None, None]
+    return lo + (codes.astype(jnp.float32) - 1.0) * step, codes != 0
+
+
+def code_grid(vals) -> tuple[np.float32, np.float32] | None:
+    """The grid ``(lo, step)`` on which every one of ``vals`` has an exact
+    1-byte code (:func:`encode`), or ``None``.
+
+    ``lo`` is the least value and ``step`` the least gap between distinct
+    values, so the grid holds where the values span at most 255 of its
+    levels and each decodes to itself.  A rating scale minus a float32
+    mean does: half stars lie 0.5 apart and ``r − μ`` is exact while
+    |r − μ| < 4.  Off-grid values are never rounded onto it: a store whose
+    values miss it keeps the float32 tile."""
+
+    v = np.unique(np.asarray(vals, np.float32))
+    if len(v) < 2:
+        grid = (np.float32(v[0] if len(v) else 0.0), np.float32(1.0))
+    else:
+        step = np.float32(np.diff(v.astype(np.float64)).min())
+        if not step >= np.finfo(np.float32).tiny:    # a normal float only
+            return None
+        grid = (v[0], step)
+    return grid if encode(v, *grid)[1].all() else None
+
+
+def _dense_tiles(blk, rr, cc, vv, p: int, q: int, mb: int, nb: int):
+    """(p, q, *tile_shape(mb, nb, "f32")) values and mask, on the host,
+    from block-routed COO entries (``blk`` the flat block index)."""
+
+    tm, tn = tile_shape(mb, nb, "f32")
     vals = np.zeros((p * q, tm, tn), np.float32)
     mask = np.zeros((p * q, tm, tn), bool)
     vals[blk, rr, cc] = vv
     mask[blk, rr, cc] = True
     return vals.reshape(p, q, tm, tn), mask.reshape(p, q, tm, tn)
+
+
+def _code_tiles(blk, rr, cc, codes, grid, p: int, q: int, mb: int, nb: int):
+    """(p, q, *tile_shape(mb, nb, "codes")) codes and the (p, q, 2) grid,
+    on the host, from block-routed COO entries and their codes."""
+
+    tm, tn = tile_shape(mb, nb, "codes")
+    tile = np.zeros((p * q, tm, tn), np.uint8)
+    tile[blk, rr, cc] = codes
+    return (tile.reshape(p, q, tm, tn),
+            np.tile(np.asarray(grid, np.float32), (p, q, 1)))
+
+
+def _tile_admit(capacity: int, mb: int, nb: int, blocks: int):
+    """``admit(encoding)`` for :func:`_host_tiles`: :func:`tile_rule` on
+    this backend and this device's memory."""
+
+    backend, limit = jax.default_backend(), device_bytes_limit()
+    return lambda enc: tile_rule(capacity, mb, nb, blocks, backend, limit,
+                                 enc)
+
+
+def _host_tiles(blk, rr, cc, vv, p: int, q: int, mb: int, nb: int,
+                admit=lambda encoding: True):
+    """The four tile fields (``TILE_FIELDS`` order) on the host, from
+    block-routed COO entries: codes where the values fit a grid
+    (:func:`code_grid`), else float32 values and a mask.  ``admit(enc)``
+    (:func:`tile_rule`) says whether a tile in that encoding may be built;
+    all four are ``None`` where it may not."""
+
+    none = (None,) * 4
+    if not admit("codes"):        # the float32 tile takes more at any size
+        return none
+    grid = code_grid(vv)
+    if grid is not None:
+        codes, _ = encode(vv, *grid)
+        return (None, None) + _code_tiles(blk, rr, cc, codes, grid, p, q,
+                                          mb, nb)
+    if not admit("f32"):
+        return none
+    return _dense_tiles(blk, rr, cc, vv, p, q, mb, nb) + (None, None)
+
+
+def _stored(rows, cols, vals, nnz):
+    """(blk, rows, cols, vals) of the real entries of (p·q, E) per-block
+    entry arrays holding ``nnz`` entries each."""
+
+    real = np.arange(rows.shape[1])[None, :] < np.asarray(nnz)[:, None]
+    return np.nonzero(real)[0], rows[real], cols[real], vals[real]
+
+
+def _with_tiles(entries: BlockEntries, tiles) -> BlockEntries:
+    """``entries`` with the four host tile fields uploaded."""
+
+    return entries._replace(**{f: None if t is None else jnp.asarray(t)
+                               for f, t in zip(TILE_FIELDS, tiles)})
 
 
 def set_padding_share(nnz_total: int, blocks: int, capacity: int) -> None:
@@ -252,25 +374,29 @@ def set_padding_share(nnz_total: int, blocks: int, capacity: int) -> None:
         1.0 - nnz_total / (blocks * capacity) if blocks * capacity else 0.0)
 
 
-def with_tile(sp: SparseProblem) -> SparseProblem:
+def with_tile(sp: SparseProblem, encoding: str | None = None
+              ) -> SparseProblem:
     """``sp`` with every block carrying its dense tile, whatever
     :func:`tile_rule` would say: what tests and benchmarks use to compare
-    the two arithmetics on one store."""
+    the arithmetics on one store.  The tile is encoded as ingest encodes
+    it (codes where the values fit a grid), or as ``encoding`` names
+    (``"f32"`` always can; ``"codes"`` raises off a grid)."""
 
-    if sp.has_tile:
+    if sp.has_tile and encoding in (None, sp.entries.tile_encoding):
         return sp
     p, q, _ = sp.rows.shape
-    nnz = np.asarray(sp.nnz).reshape(-1)
-    k = np.arange(sp.capacity)
-    real = k[None, :] < nnz[:, None]                 # (p·q, E)
-    blk = np.broadcast_to(np.arange(p * q)[:, None], real.shape)[real]
-    rows = np.asarray(sp.rows).reshape(p * q, -1)[real]
-    cols = np.asarray(sp.cols).reshape(p * q, -1)[real]
-    vals = np.asarray(sp.vals).reshape(p * q, -1)[real]
-    tv, tm = _dense_tiles(blk, rows, cols, vals, p, q, sp.mb, sp.nb)
-    return SparseProblem(sp.entries._replace(tile_vals=jnp.asarray(tv),
-                                             tile_mask=jnp.asarray(tm)),
-                         sp.nnz)
+    blk, rows, cols, vals = _stored(
+        *(np.asarray(a).reshape(p * q, -1) for a in (sp.rows, sp.cols,
+                                                     sp.vals)),
+        np.asarray(sp.nnz).reshape(-1))
+    if encoding == "f32":
+        tiles = _dense_tiles(blk, rows, cols, vals, p, q, sp.mb, sp.nb) \
+            + (None, None)
+    else:
+        tiles = _host_tiles(blk, rows, cols, vals, p, q, sp.mb, sp.nb)
+        if encoding == "codes" and tiles[2] is None:
+            raise ValueError("the store's values fit no 1-byte code grid")
+    return SparseProblem(_with_tiles(sp.entries, tiles), sp.nnz)
 
 
 def drop_tile(sp: SparseProblem) -> SparseProblem:
@@ -304,6 +430,9 @@ def _pack_sorted(blk, rr, cc, vv, p, q, mb, nb, bucket,
     nnz = host[-1]
     obs.counter("ingest_entries_total").inc(total)
     obs.gauge("ingest_tile_blocks").set(p * q if entries.has_tile else 0)
+    enc = entries.tile_encoding
+    obs.gauge("ingest_tile_bytes_per_cell").set(
+        TILE_BYTES_PER_CELL[enc] if enc else 0)
     # min over blocks: the append slack of the block that would raise first
     obs.gauge("ingest_free_slots").set(int(E - (nnz.max() if total else 0)))
     set_padding_share(total, p * q, E)
@@ -356,10 +485,10 @@ def _sorted_host(blk, rr, cc, vv, p, q, mb, nb, bucket, headroom, capacity,
     col_ptr = np.zeros((p * q, nb + 1), np.int32)
     col_ptr[:, 1:] = np.cumsum(ccnt, axis=1)
 
-    tiles = (None, None)
-    if tile and tile_rule(E, mb, nb, p * q, jax.default_backend(),
-                          device_bytes_limit()):
-        tiles = _dense_tiles(blk, rr, cc, vv, p, q, mb, nb)
+    tiles = (None,) * 4
+    if tile:
+        tiles = _host_tiles(blk, rr, cc, vv, p, q, mb, nb,
+                            _tile_admit(E, mb, nb, p * q))
     return (rows.reshape(p, q, E), cols.reshape(p, q, E),
             vals.reshape(p, q, E), valid.reshape(p, q, E),
             col_perm.reshape(p, q, E), row_ptr.reshape(p, q, mb + 1),
@@ -581,9 +710,15 @@ def append_entries(
     and ``col_perm`` is re-threaded by the same merge in the (col, row)
     dual order — so the segment-reduce fast path stays valid without ever
     re-sorting the stored prefix (DESIGN.md §11).  A dense tile, where
-    the store carries one, gets the new values and mask cells.  Capacity
-    is untouched: jitted consumers keep their compiled executables, which
-    is the point of pre-allocating ``headroom=`` at ingest.
+    the store carries one, gets the new cells: values and mask, or the
+    codes of values on the tile's grid.  A value off that grid is never
+    rounded onto it: the tile is then built afresh from the store's
+    values by ingest's rule (a new grid where they fit one, else float32
+    values and a mask where :func:`tile_rule` admits those, else no tile
+    and the segment engine), and jitted consumers compile again for it.
+    Capacity is untouched: otherwise jitted consumers keep their compiled
+    executables, which is the point of pre-allocating ``headroom=`` at
+    ingest.
 
     A (row, col) pair already present updates its value in place (an
     edited rating) and costs no slot; duplicate pairs within one append
@@ -634,15 +769,26 @@ def append_entries(
         _splice_block(ent, rptr, cptr, nnz, int(b), rr[sel], cc[sel],
                       vals[sel], mb, nb, E, label=f"({i},{j})")
 
-    tile_vals = tile_mask = None
-    if sp.has_tile:       # the dense tile follows the splice: set the cells
-        tile_vals = np.asarray(sp.entries.tile_vals).copy()
-        tile_mask = np.asarray(sp.entries.tile_mask).copy()
-        tile_vals[bi, bj, rr, cc] = vals
-        tile_mask[bi, bj, rr, cc] = True
-        tile_vals, tile_mask = jnp.asarray(tile_vals), jnp.asarray(tile_mask)
+    # the dense tile follows the splice: set the cells
+    tiles = tuple(getattr(sp.entries, f) for f in TILE_FIELDS)
+    enc = sp.entries.tile_encoding
+    if enc == "f32":
+        tv, tm = (np.asarray(t).copy() for t in tiles[:2])
+        tv[bi, bj, rr, cc] = vals
+        tm[bi, bj, rr, cc] = True
+        tiles = (tv, tm, None, None)
+    elif enc == "codes":
+        codes, ok = encode(vals, *np.asarray(tiles[3])[0, 0])
+        if ok.all():
+            tc = np.asarray(tiles[2]).copy()
+            tc[bi, bj, rr, cc] = codes
+            tiles = (None, None, tc, tiles[3])
+        else:             # off the grid: encode the whole tile afresh
+            tiles = _host_tiles(*_stored(ent["rows"], ent["cols"],
+                                         ent["vals"], nnz), p, q, mb, nb,
+                                _tile_admit(E, mb, nb, p * q))
 
-    entries = BlockEntries(
+    entries = _with_tiles(BlockEntries(
         jnp.asarray(ent["rows"].reshape(p, q, E)),
         jnp.asarray(ent["cols"].reshape(p, q, E)),
         jnp.asarray(ent["vals"].reshape(p, q, E)),
@@ -650,8 +796,7 @@ def append_entries(
         jnp.asarray(ent["col_perm"].reshape(p, q, E)),
         jnp.asarray(rptr.reshape(p, q, mb + 1)),
         jnp.asarray(cptr.reshape(p, q, nb + 1)),
-        tile_vals, tile_mask,
-    )
+    ), tiles)
     out = SparseProblem(entries,
                         jnp.asarray(nnz.reshape(p, q).astype(np.int32)))
     # the ingest plane's scoreboard: calls, entries, splice latency, and

@@ -1,7 +1,8 @@
 """Sparse block engine: store round-trips and sorted-layout invariants,
 sparse-vs-dense equivalence of objective/gradients (1e-5, segment and
 scatter methods, with and without the dense masked tile), the rule that
-builds the tile, SDDMM kernel vs oracle, minibatch sampler."""
+builds the tile and its exact 1-byte encoding, SDDMM kernel vs oracle,
+minibatch sampler."""
 
 import jax
 import jax.numpy as jnp
@@ -396,16 +397,32 @@ def test_tile_rule_reads_density_backend_and_memory():
     cpu = store_mod.TILE_CROSSOVER["cpu"]
     mb, nb = 100, 80
     above = int(np.ceil(cpu * mb * nb))
-    assert rule(above, mb, nb, 16, "cpu", None)
-    assert not rule(above - 1, mb, nb, 16, "cpu", None)
-    assert not rule(above, mb, nb, 16, "no-such-backend", None)
-    tiles = 16 * 104 * 128 * store_mod.TILE_BYTES_PER_CELL     # padded
+    for enc in ("codes", "f32"):
+        assert rule(above, mb, nb, 16, "cpu", None, enc)
+        assert not rule(above - 1, mb, nb, 16, "cpu", None, enc)
+        assert not rule(above, mb, nb, 16, "no-such-backend", None, enc)
+    # padded to whole layout tiles of the dtype: 104 rows of 32 bits,
+    # 128 rows of 8; a float32 value and a bool mask, or one code a cell
+    assert store_mod.tile_shape(mb, nb, "f32") == (104, 128)
+    assert store_mod.tile_shape(mb, nb, "codes") == (128, 128)
     share = store_mod.TILE_MEMORY_SHARE
-    assert rule(above, mb, nb, 16, "cpu", int(np.ceil(tiles / share)))
-    assert not rule(above, mb, nb, 16, "cpu", int(tiles / share) - 1)
+    for enc, tiles in (("f32", 16 * 104 * 128 * 5),
+                       ("codes", 16 * 128 * 128 * 1)):
+        assert store_mod.TILE_BYTES_PER_CELL[enc] * 16 * np.prod(
+            store_mod.tile_shape(mb, nb, enc)) == tiles
+        assert rule(above, mb, nb, 16, "cpu", int(np.ceil(tiles / share)),
+                    enc)
+        assert not rule(above, mb, nb, 16, "cpu", int(tiles / share) - 1,
+                        enc)
     tpu = store_mod.TILE_CROSSOVER["tpu"]
-    assert rule(61440, 1510, 927, 16, "tpu", 16 * 2**30)      # ML-1M, v5e
-    assert not rule(int(tpu * 1510 * 927) - 1, 1510, 927, 16, "tpu", None)
+    for enc in ("codes", "f32"):                              # ML-1M, v5e
+        assert rule(61440, 1510, 927, 16, "tpu", 16 * 2**30, enc)
+        assert not rule(int(tpu * 1510 * 927) - 1, 1510, 927, 16, "tpu",
+                        None, enc)
+    # ML-10M on one v5e: 16 code tiles take 0.77 GB, under 1/8 of the
+    # chip; float32 tiles and their mask (3.85 GB) do not fit
+    assert rule(573440, 17892, 2671, 16, "tpu", 16 * 2**30, "codes")
+    assert not rule(573440, 17892, 2671, 16, "tpu", 16 * 2**30, "f32")
 
 
 def test_ingest_builds_tile_by_the_rule(monkeypatch):
@@ -424,7 +441,9 @@ def test_ingest_builds_tile_by_the_rule(monkeypatch):
     monkeypatch.setitem(store_mod.TILE_CROSSOVER, "cpu", 0.01)
     sp, gauge = ingest()
     assert sp.has_tile and gauge == 6
-    assert store_mod.tile_shape(16, 18) == (16, 128)  # whole layout tiles
+    # continuous values fit no code grid: the float32 tile and its mask
+    assert sp.entries.tile_encoding == "f32"
+    assert store_mod.tile_shape(16, 18, "f32") == (16, 128)  # layout tiles
     assert sp.entries.tile_vals.shape == (3, 2, 16, 128)
     assert sp.entries.tile_mask.dtype == jnp.bool_
     xb, maskb = sparse.to_dense(sp)
@@ -445,6 +464,172 @@ def test_ingest_builds_tile_by_the_rule(monkeypatch):
     monkeypatch.setitem(store_mod.TILE_CROSSOVER, "cpu", 2.0)
     sp, gauge = ingest()
     assert not sp.has_tile and gauge == 0
+
+
+# ---------------------------------------------------------------------------
+# The tile's exact 1-byte encoding
+# ---------------------------------------------------------------------------
+
+
+def _rated(levels, m=60, n=44, count=900, seed=0):
+    """COO ratings drawn from ``levels`` minus their float32 mean, as
+    ``CompletionProblem.from_entries(mean_center=True)`` stores them."""
+
+    rng = np.random.default_rng(seed)
+    lin = rng.choice(m * n, count, replace=False)
+    vals = np.asarray(levels, np.float32)[rng.integers(0, len(levels),
+                                                       count)]
+    vals[:len(levels)] = levels                     # every level is drawn
+    return lin // n, lin % n, vals - float(vals.mean())
+
+
+HALF_STARS = np.arange(1, 11) * 0.5
+STARS = np.arange(1, 6, dtype=np.float64)
+
+
+@pytest.mark.parametrize("levels,step", [(HALF_STARS, 0.5), (STARS, 1.0)],
+                         ids=["half-stars", "integers"])
+def test_code_grid_round_trips_centred_ratings(levels, step):
+    """Centred half-star and integer ratings fit one grid, and every
+    stored value decodes from its code to itself bit for bit."""
+
+    _, _, vals = _rated(levels)
+    lo, got = store_mod.code_grid(vals)
+    assert got == np.float32(step) and lo == vals.min()
+    codes, ok = store_mod.encode(vals, lo, got)
+    assert ok.all() and codes.dtype == np.uint8
+    assert codes.min() == 1 and codes.max() == len(levels)
+    back = lo + (codes.astype(np.float32) - 1) * got
+    np.testing.assert_array_equal(back.view(np.uint32),
+                                  vals.view(np.uint32))
+
+
+@pytest.mark.parametrize("case", ["off-grid", "over-255-levels",
+                                  "continuous"])
+def test_code_grid_falls_back_to_float32_tile(case):
+    """Values no 255-level grid holds exactly fit no codes: ``code_grid``
+    says so, and an ingest above the crossover keeps the float32 tile and
+    its mask, every value as given (none is rounded onto a grid)."""
+
+    rows, cols, vals = _rated(HALF_STARS)
+    if case == "off-grid":
+        vals[7] += np.float32(0.1)          # 0.1 off a half star
+    elif case == "over-255-levels":
+        vals = np.arange(len(vals), dtype=np.float32) * 0.25   # 900 levels
+    else:
+        vals = np.random.default_rng(1).normal(size=len(vals)).astype(
+            np.float32)
+    assert store_mod.code_grid(vals) is None
+    sp, _ = sparse.from_entries(rows, cols, vals, 60, 44, 3, 2, bucket=32)
+    sp = sparse.with_tile(sp)
+    assert sp.entries.tile_encoding == "f32"
+    assert sp.entries.tile_codes is None and sp.entries.tile_grid is None
+    xb, maskb = sparse.to_dense(sp)
+    tv = np.asarray(sp.entries.tile_vals)[..., :sp.mb, :sp.nb]
+    np.testing.assert_array_equal(tv.view(np.uint32), xb.view(np.uint32))
+    with pytest.raises(ValueError, match="no 1-byte code grid"):
+        sparse.with_tile(sparse.drop_tile(sp), "codes")
+
+
+def test_code_grid_edge_cases():
+    """A single value or none still has a grid; an empty store ingests a
+    tile of unobserved cells; a gap lost to float32 rounding has none."""
+
+    assert store_mod.code_grid(np.float32([2.5, 2.5])) == (2.5, 1.0)
+    assert store_mod.code_grid(np.float32([])) == (0.0, 1.0)
+    tiny = np.float32([1.0, np.nextafter(np.float32(1), np.float32(2))])
+    assert store_mod.code_grid(tiny) is not None          # 2 levels, exact
+    assert store_mod.code_grid(np.float32([0, 1e-40])) is None  # denormal
+    assert store_mod.code_grid(np.float32([0, np.nan])) is None
+    assert store_mod.code_grid(np.float32([0, np.inf])) is None
+
+
+@pytest.mark.parametrize("case,enc,nbytes", [
+    ("half-stars", "codes", 1), ("off-grid", "f32", 5),
+    ("below-crossover", None, 0)])
+def test_ingest_tile_bytes_per_cell_gauge(monkeypatch, case, enc, nbytes):
+    """Every ingest sets ``ingest_tile_bytes_per_cell`` beside
+    ``ingest_tile_blocks``: 1 for a code tile, 5 for the float32 tile and
+    its mask, 0 for no tile."""
+
+    rows, cols, vals = _rated(HALF_STARS)
+    if case == "off-grid":
+        vals[3] += np.float32(0.01)
+    monkeypatch.setitem(store_mod.TILE_CROSSOVER, "cpu",
+                        2.0 if case == "below-crossover" else 0.0)
+    sp, _ = sparse.from_entries(rows, cols, vals, 60, 44, 3, 2, bucket=32)
+    assert sp.entries.tile_encoding == enc
+    assert obs.gauge("ingest_tile_bytes_per_cell").value == nbytes
+    assert obs.gauge("ingest_tile_blocks").value == (6 if enc else 0)
+    if enc == "codes":
+        tc = sp.entries.tile_codes
+        assert tc.dtype == jnp.uint8 and tc.shape == (3, 2, 32, 128)
+        assert sp.entries.tile_grid.shape == (3, 2, 2)
+        xb, maskb = sparse.to_dense(sp)
+        codes = np.asarray(tc)[..., :sp.mb, :sp.nb]
+        np.testing.assert_array_equal(codes > 0, maskb > 0)
+        assert not np.asarray(tc)[..., sp.mb:, :].any()
+        assert not np.asarray(tc)[..., sp.nb:].any()
+
+
+def test_code_tile_memory_rule_admits_what_float32_would_not(monkeypatch):
+    """At a device memory the float32 tile passes and the code tile does
+    not, ingest of on-grid values builds the code tile."""
+
+    rows, cols, vals = _rated(HALF_STARS)
+    monkeypatch.setitem(store_mod.TILE_CROSSOVER, "cpu", 0.0)
+    f32_bytes = 6 * 24 * 128 * 5            # mb 20 -> 24 rows of 32 bits
+    monkeypatch.setattr(store_mod, "device_bytes_limit",
+                        lambda: int(f32_bytes / store_mod.TILE_MEMORY_SHARE)
+                        - 1)
+    sp, _ = sparse.from_entries(rows, cols, vals, 60, 44, 3, 2, bucket=32)
+    assert sp.entries.tile_encoding == "codes"
+    vals[3] += np.float32(0.01)
+    sp, _ = sparse.from_entries(rows, cols, vals, 60, 44, 3, 2, bucket=32)
+    assert not sp.has_tile
+
+
+def _three_paths(levels, seed):
+    rows, cols, vals = _rated(levels, seed=seed)
+    sp, _ = sparse.from_entries(rows, cols, vals, 60, 44, 3, 2, bucket=32)
+    return (sparse.with_tile(sp, "codes"),
+            sparse.with_tile(sparse.drop_tile(sp), "f32"),
+            sparse.drop_tile(sp))
+
+
+@pytest.mark.parametrize("levels,seed", [(HALF_STARS, 0), (STARS, 1)],
+                         ids=["half-stars", "integers"])
+def test_code_tile_terms_equal_float32_tile_bit_for_bit(levels, seed):
+    """A code tile's (f, gU, gW), one block per loop step as ``wave_step``
+    visits them, and its chunk cost equal the float32 tile's of the same
+    store bit for bit (the decode gives the stored values exactly and the
+    f-term sums the block's own cells whatever the padding), and the
+    segment engine's within 1e-5."""
+
+    codes, f32, seg = _three_paths(levels, seed)
+    key = jax.random.PRNGKey(seed)
+    ku, kw = jax.random.split(key)
+    U = jax.random.normal(ku, (3, 2, codes.mb, 4))
+    W = jax.random.normal(kw, (3, 2, codes.nb, 4))
+    bi = jnp.array([[0, 1, 2], [2, 0, 1]])
+    bj = jnp.array([[0, 1, 1], [0, 0, 1]])
+    u3, w3 = U[bi, bj], W[bi, bj]
+    a = sparse_obj.f_grads_at(codes.entries, bi, bj, u3, w3)
+    b = sparse_obj.f_grads_at(f32.entries, bi, bj, u3, w3)
+    c = sparse_obj.f_grads_at(seg.entries, bi, bj, u3, w3)
+    for x, y, z in zip(a, b, c):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        _close(x, z)
+    def block(sp):                          # one block, vmap-style
+        one = jax.tree.map(lambda t: t[1, 0], sp.entries)
+        return sparse_obj.f_grads_sparse(one, U[1, 0], W[1, 0])
+
+    for x, y in zip(block(codes), block(f32)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    costs = [sparse_obj.total_report_cost_sparse(s, U, W, 0.1)
+             for s in (codes, f32, seg)]
+    assert float(costs[0]) == float(costs[1])
+    _close(costs[0], costs[2])
 
 
 def test_minibatch_and_placed_stores_carry_no_tile():

@@ -15,6 +15,7 @@ from repro.data import lowrank_problem
 from repro.mc import (CompletionProblem, Incremental, Trainer, Wave,
                       make_schedule)
 from repro import sparse
+from repro.sparse import store
 
 from test_sparse import check_sorted_store_invariants
 from _tile_paths import PATHS, on_path
@@ -149,6 +150,126 @@ def test_append_overflow_raises_with_headroom_hint():
     with pytest.raises(ValueError, match="headroom"):
         sparse.append_entries(sp, np.array(newr), np.array(newc),
                               np.ones(len(newr), np.float32))
+
+
+def _half_star_problem(seed=0, m=60, n=48, p=3, q=2):
+    """Centred half-star ratings (1 to 5 stars) split into a base store
+    carrying its tile of 1-byte codes and a streamed remainder; the base
+    holds every level, so the union has the base's grid."""
+
+    rng = np.random.default_rng(seed)
+    lin = rng.choice(m * n, 700, replace=False)
+    rr, cc = lin // n, lin % n
+    stars = np.arange(2, 11) * np.float32(0.5)
+    vv = stars[rng.integers(0, len(stars), len(lin))]
+    vv[:len(stars)] = stars
+    mu = np.float32(vv.mean())
+    vv = vv - mu
+    base, stream = np.arange(500), np.arange(500, len(lin))
+    sp, _ = sparse.from_entries(rr[base], cc[base], vv[base], m, n, p, q,
+                                bucket=32, headroom=160)
+    sp = sparse.with_tile(sp, "codes")
+    return sp, (rr, cc, vv), (base, stream), mu
+
+
+def test_append_on_grid_patches_codes_cell_for_cell():
+    """Values on the tile's grid are written as their codes: the grid
+    stays, only the appended cells change, and the tile equals a fresh
+    ingest's of the union code for code."""
+
+    sp, (rr, cc, vv), (base, stream), _ = _half_star_problem()
+    out = sparse.append_entries(sp, rr[stream], cc[stream], vv[stream])
+    check_sorted_store_invariants(out)
+    assert out.entries.tile_encoding == "codes"
+    np.testing.assert_array_equal(np.asarray(out.entries.tile_grid),
+                                  np.asarray(sp.entries.tile_grid))
+    before = np.asarray(sp.entries.tile_codes)
+    after = np.asarray(out.entries.tile_codes)
+    mb, nb = sp.mb, sp.nb
+    touched = np.zeros(after.shape, bool)
+    touched[rr[stream] // mb, cc[stream] // nb, rr[stream] % mb,
+            cc[stream] % nb] = True
+    np.testing.assert_array_equal(after[~touched], before[~touched])
+    assert (after[touched] > 0).all()
+    ref, _ = sparse.from_entries(rr, cc, vv, 60, 48, 3, 2, bucket=32)
+    np.testing.assert_array_equal(
+        after, np.asarray(sparse.with_tile(ref, "codes").entries.tile_codes))
+
+
+@pytest.mark.parametrize("stars,enc", [(0.5, "codes"), (1.3, "f32")],
+                         ids=["new-grid", "f32"])
+def test_append_off_grid_encodes_the_tile_afresh(stars, enc):
+    """A value off the tile's grid is not rounded onto it: the whole tile
+    is encoded afresh from the store's values, as ingest encodes them.  A
+    half star below every stored one moves the grid (still codes); 1.3
+    stars fits no grid, so the tile becomes float32 values and a mask.
+    Either way it is a fresh ingest's tile of the union, and the f-term
+    is the segment engine's."""
+
+    sp, (rr, cc, vv), (base, stream), mu = _half_star_problem(seed=1)
+    new = np.float32(stars) - mu
+    vals = vv[stream].copy()
+    vals[0] = new
+    out = sparse.append_entries(sp, rr[stream], cc[stream], vals)
+    assert out.entries.tile_encoding == enc
+    allv = vv.copy()
+    allv[stream] = vals
+    ref, _ = sparse.from_entries(rr, cc, allv, 60, 48, 3, 2, bucket=32)
+    fresh = sparse.with_tile(ref).entries
+    assert fresh.tile_encoding == enc
+    for f in ("tile_vals", "tile_mask", "tile_codes", "tile_grid"):
+        a, b = getattr(out.entries, f), getattr(fresh, f)
+        assert (a is None) == (b is None), f
+        if a is not None:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    xa, _ = sparse.to_dense(out)
+    r0, c0 = int(rr[stream[0]]), int(cc[stream[0]])
+    mb, nb = sp.mb, sp.nb
+    assert xa[r0 // mb, c0 // nb, r0 % mb, c0 % nb] == new
+    key = jax.random.PRNGKey(0)
+    U = jax.random.normal(key, (3, 2, mb, 4))
+    W = jax.random.normal(key, (3, 2, nb, 4))
+    ga = waves.full_gradients(out, U, W, rho=0.1, lam=0.01)
+    gs = waves.full_gradients(sparse.drop_tile(out), U, W, rho=0.1, lam=0.01)
+    for a, b in zip(ga, gs):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5 * scale)
+
+
+def test_append_off_grid_follows_the_memory_rule(monkeypatch):
+    """Off the grid, an append builds the tile ingest would build, by
+    ``tile_rule`` on the store's capacity and the device's memory: where
+    the memory holds the codes but not a float32 tile and mask, the new
+    grid stays codes and values that fit no grid leave no tile, so the
+    f-term goes back to the segment engine."""
+
+    sp, (rr, cc, vv), (base, stream), mu = _half_star_problem(seed=2)
+    p, q = sp.rows.shape[:2]
+    tm, tn = store.tile_shape(sp.mb, sp.nb, "codes")
+    limit = int(p * q * tm * tn * store.TILE_BYTES_PER_CELL["codes"]
+                / store.TILE_MEMORY_SHARE)
+    monkeypatch.setattr(store, "device_bytes_limit", lambda: limit)
+    rule = dict(capacity=sp.capacity, mb=sp.mb, nb=sp.nb, blocks=p * q,
+                backend=jax.default_backend(), bytes_limit=limit)
+    assert store.tile_rule(**rule, encoding="codes")
+    assert not store.tile_rule(**rule, encoding="f32")
+    mb, nb = sp.mb, sp.nb
+    r0, c0 = int(rr[stream[0]]), int(cc[stream[0]])
+    for stars, enc in ((0.5, "codes"), (1.3, None)):
+        vals = vv[stream].copy()
+        vals[0] = np.float32(stars) - mu
+        out = sparse.append_entries(sp, rr[stream], cc[stream], vals)
+        assert out.entries.tile_encoding == enc
+        assert out.capacity == sp.capacity
+        xa, _ = sparse.to_dense(out)
+        assert xa[r0 // mb, c0 // nb, r0 % mb, c0 % nb] == vals[0]
+        if enc is not None:
+            x, m = store.decode(out.entries.tile_codes,
+                                out.entries.tile_grid)
+            np.testing.assert_array_equal(
+                np.asarray(x)[..., :mb, :nb] * np.asarray(m)[..., :mb, :nb],
+                xa)
 
 
 def test_append_validates_inputs():

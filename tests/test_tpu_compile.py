@@ -34,7 +34,8 @@ from repro.kernels.sddmm.segment_kernel import sddmm_segment_grad_pallas
 from repro.mesh import MeshPlan
 from repro.sparse.entries import BlockEntries
 from repro.sparse.store import (DEFAULT_BUCKET, SparseProblem,
-                                bucketed_capacity, tile_rule, tile_shape)
+                                TILE_BYTES_PER_CELL, bucketed_capacity,
+                                tile_rule, tile_shape)
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM_BYTES = 16 * 2**30          # one v5e chip
@@ -95,15 +96,22 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _store(p, q, mb, nb, E, sharding, tile=False):
+def _store(p, q, mb, nb, E, sharding, tile=None):
     """SparseProblem of shapes: every leaf stacked over the (p, q) grid,
-    with the dense masked tile when ``tile``."""
+    with the dense masked tile in the encoding ``tile`` names."""
 
     i32, f32 = jnp.int32, jnp.float32
     grid = (p, q)
-    shape = grid + tile_shape(mb, nb)
-    tiles = ((_sds(shape, f32, sharding), _sds(shape, jnp.bool_, sharding))
-             if tile else (None, None))
+    tiles = dict.fromkeys(("tile_vals", "tile_mask", "tile_codes",
+                           "tile_grid"))
+    if tile is not None:
+        shape = grid + tile_shape(mb, nb, tile)
+    if tile == "f32":
+        tiles.update(tile_vals=_sds(shape, f32, sharding),
+                     tile_mask=_sds(shape, jnp.bool_, sharding))
+    elif tile == "codes":
+        tiles.update(tile_codes=_sds(shape, jnp.uint8, sharding),
+                     tile_grid=_sds(grid + (2,), f32, sharding))
     return SparseProblem(
         BlockEntries(
             rows=_sds(grid + (E,), i32, sharding),
@@ -113,8 +121,7 @@ def _store(p, q, mb, nb, E, sharding, tile=False):
             col_perm=_sds(grid + (E,), i32, sharding),
             row_ptr=_sds(grid + (mb + 1,), i32, sharding),
             col_ptr=_sds(grid + (nb + 1,), i32, sharding),
-            tile_vals=tiles[0],
-            tile_mask=tiles[1],
+            **tiles,
         ),
         _sds(grid, i32, sharding),
     )
@@ -197,24 +204,30 @@ def test_wave_step_fits_one_chip_at_ml1m(one_chip, ml1m):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-def test_wave_step_with_tile_fits_one_chip_at_ml1m(one_chip, ml1m):
+@pytest.mark.parametrize("tile", ["codes", "f32"])
+def test_wave_step_with_tile_fits_one_chip_at_ml1m(one_chip, ml1m, tile):
     """The smoke's training step on dense masked tiles (the path the
     ML-1M blocks take on the chip) compiles and fits one chip's HBM, and
     reads each block's tile inside its products: no block is copied out
-    first, so its temporaries stay under one block's tile."""
+    first, so its temporaries stay under one block's tile in its
+    encoding (four fifths of it: a copy of the block's cells would pass
+    that).  ``"codes"`` is what ingest builds there, integer ratings
+    minus their mean; ``"f32"`` (values + mask) what stores off a grid
+    take."""
 
     size, block, headroom = ml1m
     g = size.grid
     mb, nb, E = block(g, headroom)
+    assert tile_rule(E, mb, nb, g * g, "tpu", HBM_BYTES, tile)
     tables = _wave_tables(g, g, one_chip)
     compiled = waves.wave_step.lower(
-        _store(g, g, mb, nb, E, one_chip, tile=True),
+        _store(g, g, mb, nb, E, one_chip, tile=tile),
         _state(g, g, mb, nb, size.rank, one_chip),
         tables,
         rho=1e2, lam=1e-6, a=1e-3, b=5e-7,
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
-    one_tile = mb * nb * 4
+    one_tile = mb * nb * TILE_BYTES_PER_CELL[tile] * 4 // 5
     assert compiled.memory_analysis().temp_size_in_bytes < one_tile
 
 
@@ -223,20 +236,34 @@ def test_wave_step_with_tile_fits_one_chip_at_ml1m(one_chip, ml1m):
 ML10M = dict(g=4, mb=17892, nb=2671, r=64, E=573440)
 
 
-def test_wave_step_and_cost_fit_one_chip_at_ml10m(one_chip):
-    """The ML-10M training step (tile-less blocks, the chip's segment
-    chunk) and the chunk cost compile for the chip and fit it.  Each
-    visits one block per loop step, so its temporaries are one block's
-    per-entry (E, r) float32 arrays: the row gathers, the contributions
-    and their chunk prefixes, at most eight alive at once (1.17 GB).  A
-    wave vmapped over its twelve blocks needs 1.76 GB for a single such
+@pytest.mark.parametrize("tile", ["codes", None])
+def test_wave_step_and_cost_fit_one_chip_at_ml10m(one_chip, tile):
+    """The ML-10M training step and the chunk cost compile for the chip
+    and fit it, one block per loop step.
+
+    ``"codes"``: the store ingest builds there.  Its tiles of 1-byte
+    codes (0.77 GB for all sixteen) pass ``tile_rule``; float32 tiles
+    with a mask (3.85 GB) would not.  A visit's temporaries are its
+    tile's float32 residual and product: under three blocks' residuals,
+    where the sixteen the chunk cost once vmapped took 3.1 GB each.
+
+    ``None``: tile-less blocks (a mesh-placed store), on the chip's
+    segment chunk.  A visit's temporaries are one block's per-entry
+    (E, r) float32 arrays: the row gathers, the contributions and their
+    chunk prefixes, at most eight alive at once (1.17 GB).  A wave
+    vmapped over its twelve blocks needs 1.76 GB for a single such
     array, and the slab gather of the engine this one replaced took
     13.1 GB alone."""
 
     g, mb, nb, r, E = (ML10M[k] for k in ("g", "mb", "nb", "r", "E"))
-    assert not tile_rule(E, mb, nb, g * g, "tpu", HBM_BYTES)
-    bound = 8 * E * r * 4
-    store = _store(g, g, mb, nb, E, one_chip)
+    assert tile_rule(E, mb, nb, g * g, "tpu", HBM_BYTES, "codes")
+    assert not tile_rule(E, mb, nb, g * g, "tpu", HBM_BYTES, "f32")
+    if tile is None:
+        bound = 8 * E * r * 4
+    else:
+        tm, tn = tile_shape(mb, nb, tile)
+        bound = 3 * tm * tn * 4
+    store = _store(g, g, mb, nb, E, one_chip, tile=tile)
     state = _state(g, g, mb, nb, r, one_chip)
     step = waves.wave_step.lower(
         store, state, _wave_tables(g, g, one_chip),
